@@ -596,9 +596,11 @@ class LM:
         scratch page (serving/page_pool.NULL_PAGE)."""
         cfg = self.cfg
         assert self.supports_paging(), cfg.name
-        z = jnp.zeros((cfg.n_repeats, num_pages, block, cfg.n_kv_heads,
-                       cfg.d_head), cfg.compute_dtype)
-        return tuple({"k": z, "v": z} for _ in cfg.pattern)
+        shape = (cfg.n_repeats, num_pages, block, cfg.n_kv_heads, cfg.d_head)
+        # one buffer per leaf: the engine donates the arena, and a buffer
+        # may be donated only once per call
+        return tuple({n: jnp.zeros(shape, cfg.compute_dtype)
+                      for n in ("k", "v")} for _ in cfg.pattern)
 
     def prefill_paged(self, params, arena, page_tables, tokens, pos0,
                       active=None):

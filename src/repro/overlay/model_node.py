@@ -346,14 +346,16 @@ class ModelNode:
         self._real_sched.submit(
             Request(rid, payload["prompt"], max_new=min(n_out, 16)))
 
-    def _drain_real(self, rid: int) -> list:
+    def _drain_real(self, rid: int):
+        """Step the scheduler until request ``rid`` is done; its engine
+        Result, or None if the scheduler never held it."""
         sched = self._real_sched
         while rid not in self._real_results and (sched.queue or sched.active):
             sched.step()
             for r in sched.done:
-                self._real_results[r.req_id] = r.output
+                self._real_results[r.req_id] = r
             sched.done.clear()
-        return self._real_results.pop(rid, [])
+        return self._real_results.pop(rid, None)
 
     def _finish(self, net, payload: dict, n_out: int):
         self.active_requests = max(0, self.active_requests - 1)
@@ -367,7 +369,8 @@ class ModelNode:
             if rid is None:      # respond_fn was unset mid-flight; late entry
                 self._submit_real(payload, n_out)
                 rid = self._rid_by_msg.pop(payload["msg_id"])
-            out = self._drain_real(rid)
+            r = self._drain_real(rid)
+            out = r.output if r is not None else []
         else:
             out = [int(x) % 1000 for x in range(n_out)]
         resp = {"msg_id": payload["msg_id"],

@@ -22,6 +22,7 @@ import itertools
 from dataclasses import dataclass, field
 
 from repro.overlay.user_node import _decode
+from repro.serving.page_pool import OutOfPages
 from repro.serving.prefix_cache import _chain_hashes
 
 
@@ -115,22 +116,26 @@ class Replicator:
         node.metrics["kv_wire_bytes"] += len(msg["data"])
         if len(f.chunks) < f.total:
             return
-        # any failure from here on — OutOfPages, a truncated/garbled blob
-        # from a byzantine or version-skewed holder, a shape mismatch —
-        # must degrade to plain prefill, never escape into the node's
-        # message loop (import_pages releases its pages on the way out)
+        # a full local arena (OutOfPages) or a truncated/garbled blob from
+        # a byzantine or version-skewed holder degrades to plain prefill
+        # (import_pages refuses a bad buffer with ValueError before it
+        # takes a page).  Anything else — a device error among them — is
+        # a fault, and propagates.
+        # the holder may cover fewer blocks than requested (partial
+        # eviction since the sketch broadcast): import what arrived
+        depth = min(int(msg.get("depth", f.depth)), f.depth)
         try:
-            buf = _decode(b"".join(f.chunks[i] for i in range(f.total)))
-            # the holder may cover fewer blocks than requested (partial
-            # eviction since the sketch broadcast): import what arrived
-            depth = min(int(msg.get("depth", f.depth)), f.depth)
-            n_pages = int(buf["n_pages"])
-            self.node.real_engine.import_pages(buf, f.chains[:depth])
-        except Exception:            # OutOfPages included
+            try:
+                buf = _decode(b"".join(f.chunks[i] for i in range(f.total)))
+            except Exception as e:      # whatever msgpack makes of garbage
+                raise ValueError(f"undecodable page buffer: {e!r}") from e
+            handle = self.node.real_engine.import_pages(buf,
+                                                        f.chains[:depth])
+        except (OutOfPages, ValueError):
             node.metrics["kv_import_failures"] += 1
             self._finish(net, msg["fetch_id"], imported=False)
             return
-        node.metrics["kv_imported_pages"] += n_pages
+        node.metrics["kv_imported_pages"] += len(handle.pages)
         self._finish(net, msg["fetch_id"], imported=True)
 
     # ------------------------------------------------------------------

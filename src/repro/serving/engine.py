@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.models.lm import (arena_gather_pages, arena_scatter_pages,
                              cache_slot_read, cache_slot_write)
@@ -117,7 +118,14 @@ class RealEngine:
                  num_pages: Optional[int] = None):
         self.cfg = cfg
         self.model = model
-        self.params = params
+        # serve in the compute dtype: a float32 master copy of a full-width
+        # model does not fit one chip beside its KV arena
+        self.params = jax.tree.map(
+            lambda a: a.astype(cfg.compute_dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+        # the engine's state lives where its params do: nodes sharing a
+        # host each keep their arena on their own chip
+        self.device = next(iter(jax.tree.leaves(self.params)[0].devices()))
         self.max_len = max_len
         self.prefix_cache = PrefixCache(cache_bytes)
         # partial-prefix KV reuse is an attention-cache property: a slot per
@@ -164,7 +172,10 @@ class RealEngine:
             # under pressure the prefix cache is evicted page-by-page
             self.num_pages = num_pages or (1 + 16 * self.max_pages)
             self.allocator = PageAllocator(self.num_pages)
-            self.arena = model.paged_arena_zeros(self.num_pages, BLOCK)
+            self.arena = jax.jit(
+                model.paged_arena_zeros, static_argnums=(0, 1),
+                out_shardings=SingleDeviceSharding(self.device))(
+                    self.num_pages, BLOCK)
             self.page_bytes = sum(
                 x.shape[0] * BLOCK * x.shape[3] * x.shape[4]
                 * x.dtype.itemsize for x in jax.tree.leaves(self.arena))
@@ -337,20 +348,35 @@ class RealEngine:
         arena cannot host the pages even after LRU eviction; any pages
         allocated before the failure are released — a failed import
         leaves allocator and arena exactly as they were, and the caller
-        falls back to plain prefill."""
+        falls back to plain prefill.  A buffer that is not an export of
+        this model's arena (garbled, short, wrong shape) raises
+        ValueError before a page is taken; an error from the device
+        scatter propagates as it is."""
         assert self.paged, "page import requires the paged pool"
-        n = int(buf["n_pages"])
+        # the whole buffer is parsed on the host before a page is taken:
+        # whatever a peer's bytes make the decoder raise is a bad payload
+        try:
+            n = int(buf["n_pages"])
+            blocks = tuple(
+                {name: decompress_kv_blocks(layer[name],
+                                            self.cfg.compute_dtype)
+                 for name in ("k", "v")}
+                for layer in buf["layers"])
+        except Exception as e:
+            raise ValueError(f"malformed page buffer: {e!r}") from e
         chains = list(chains)[:n]
         if n < 1 or len(chains) < n:
             raise ValueError(f"import of {n} pages with {len(chains)} "
                              f"chain digests")
+        want = [{name: a.shape[:1] + (n,) + a.shape[2:]
+                 for name, a in layer.items()} for layer in self.arena]
+        got = [{name: b.shape for name, b in layer.items()}
+               for layer in blocks]
+        if got != want:
+            raise ValueError(f"page buffer shapes {got} do not fit "
+                             f"the arena ({want})")
         pages = self.alloc_pages(n)
         try:
-            dtype = self.cfg.compute_dtype
-            blocks = tuple(
-                {name: decompress_kv_blocks(layer[name], dtype)
-                 for name in ("k", "v")}
-                for layer in buf["layers"])
             self.arena = self._scatter_pages(
                 self.arena, jnp.asarray(pages, jnp.int32), blocks)
         except BaseException:

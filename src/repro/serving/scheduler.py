@@ -32,6 +32,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -72,8 +73,10 @@ class Scheduler:
         # cfg.spec_enabled/spec_k are serving policy, not arch traits)
         self.spec = engine.spec
         self._spec_w = engine.cfg.spec_k + 1 if self.spec else 1
-        self._logits = jnp.zeros((max_active, engine.cfg.padded_vocab),
-                                 jnp.float32)
+        # device state sits beside the engine's params and arena
+        self._logits = jax.device_put(
+            np.zeros((max_active, engine.cfg.padded_vocab), np.float32),
+            engine.device)
         if engine.paged:
             # page-table pool: rows of physical page ids into the engine's
             # shared arena; 0 = scratch page (inactive / unallocated)
@@ -82,8 +85,9 @@ class Scheduler:
         else:
             # dense pool: one batched cache pytree allocated once for the
             # engine's max_len
-            self._cache = engine.model.cache_zeros(max_active,
-                                                   engine.max_len)
+            self._cache = jax.device_put(
+                engine.model.cache_zeros(max_active, engine.max_len),
+                engine.device)
             self._ptab = None
 
     @property

@@ -12,6 +12,8 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
+    AUTO = (AxisType.Auto,) * 2
     import numpy as np
     from repro.configs import base
     from repro.distributed import sharding
@@ -24,7 +26,7 @@ SCRIPT = textwrap.dedent("""
     # widen dims so a (4, 2) mesh divides them
     import dataclasses
     cfg = dataclasses.replace(cfg, d_model=64, d_ff=128, vocab=512)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
     model = build_model(cfg)
     adamw = opt_lib.AdamWConfig(lr=1e-3)
     step = make_train_step(cfg, model, adamw, block_q=16)
@@ -48,7 +50,8 @@ SCRIPT = textwrap.dedent("""
     # checkpoint on (4,2), restore resharded onto (2,4) — elastic re-mesh
     with tempfile.TemporaryDirectory() as d:
         ckpt_lib.save(d, 1, params2)
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                              axis_types=AUTO)
         p_sh2 = sharding.param_shardings(cfg, params2, mesh2, train=True)
         restored, _ = ckpt_lib.restore(d, 1, params2, shardings=p_sh2)
         same = all(np.allclose(np.asarray(a), np.asarray(b))

@@ -63,6 +63,8 @@ def test_decode_attention(B, H, Hkv, S, D, window, softcap, ns):
     (3, 4, 1, 64, 16, 9, 6, None, None),
     (1, 8, 8, 128, 32, 6, 3, 48, None),
     (2, 2, 2, 64, 32, 8, 4, None, 50.0),
+    (2, 32, 8, 80, 32, 9, 4, None, None),       # h2o-danube-1.8b heads
+    (2, 32, 8, 80, 32, 9, 4, 40, None),
 ])
 def test_paged_decode_attention(B, H, Hkv, D, blk, P, n_pg, window,
                                 softcap):

@@ -20,6 +20,7 @@ from repro.net import messages
 from repro.net.simnet import SimNet
 from repro.overlay.model_node import ModelNode
 from repro.overlay.probe import ResponseSink, direct_payload
+from repro.overlay.user_node import _decode, _encode
 from repro.serving.engine import RealEngine, Request
 from repro.serving.prefix_cache import BLOCK, _chain_hashes
 from repro.training.compression import (compress_kv_blocks,
@@ -252,6 +253,75 @@ def test_garbled_pages_fall_back_without_crashing(gt):
     assert nodes[1].metrics["kv_import_failures"] == 1
     assert nodes[1].metrics["kv_fallbacks"] == 1
     nodes[1].real_engine.allocator.check()
+
+
+def _rewrite_records(edit):
+    """A tamper that re-encodes a one-chunk kv_pages payload with
+    ``edit`` applied to every K/V record: it decodes, but is not an
+    export of the arena."""
+    def tamper(msg):
+        buf = _decode(msg["data"])
+        for layer in buf["layers"]:
+            for rec in layer.values():
+                edit(rec)
+        return _encode(buf)
+    return tamper
+
+
+def _infinite_pages(msg):
+    buf = _decode(msg["data"])
+    buf["n_pages"] = float("inf")
+    return _encode(buf)
+
+
+@pytest.mark.parametrize("tamper", [
+    lambda msg: msg["data"][:len(msg["data"]) // 2],    # truncated blob
+    _rewrite_records(lambda rec: rec.update(data=rec["data"][:8])),
+    _rewrite_records(lambda rec: rec.update(mode="raw", dtype="float99")),
+    _rewrite_records(lambda rec: rec.update(mode="int8",
+                                            shape=rec["shape"][:1])),
+    _infinite_pages,
+], ids=["truncated", "short_arrays", "bad_dtype", "int8_1d_shape",
+        "inf_pages"])
+def test_short_payload_falls_back(gt, tamper):
+    """A payload cut short in flight, or one whose fields no export
+    carries (short arrays, an unknown dtype, a shape the codec cannot
+    read, a non-integral page count), is refused by the importer and the
+    request is served by prefill."""
+    net, nodes, sink = _build(gt, True)
+    _seed_and_pressure(net, nodes)
+    net.call_after(0.01, nodes[1]._process, net,
+                   direct_payload("sib0", SHARED + [10] * 8, 4))
+    real_send = net.send
+
+    def send(src, dst, msg, size_bytes=1024):
+        if isinstance(msg, dict) and msg.get("type") == "kv_pages":
+            assert msg["total"] == 1
+            msg = dict(msg, data=tamper(msg))
+        real_send(src, dst, msg, size_bytes)
+    net.send = send
+    net.run_until(net.t + 60)
+    assert "sib0" in sink.got
+    assert nodes[1].metrics["kv_import_failures"] == 1
+    assert nodes[1].metrics["kv_fallbacks"] == 1
+    nodes[1].real_engine.allocator.check()
+
+
+def test_unexpected_import_error_propagates(gt, monkeypatch):
+    """Only a full arena or a bad payload fall back: any other error from
+    ``import_pages`` (a device fault, say) escapes the message loop
+    instead of vanishing into a counter."""
+    net, nodes, sink = _build(gt, True)
+    _seed_and_pressure(net, nodes)
+
+    def fault(buf, chains):
+        raise RuntimeError("device fault")
+    monkeypatch.setattr(nodes[1].real_engine, "import_pages", fault)
+    net.call_after(0.01, nodes[1]._process, net,
+                   direct_payload("sib0", SHARED + [10] * 8, 4))
+    with pytest.raises(RuntimeError, match="device fault"):
+        net.run_until(net.t + 60)
+    assert nodes[1].metrics["kv_import_failures"] == 0
 
 
 def test_fetch_timeout_falls_back(gt):

@@ -161,19 +161,19 @@ def test_importer_out_of_pages_releases_and_falls_back(gt):
 
 
 def test_import_failure_mid_scatter_releases_pages(gt, monkeypatch):
-    """A failure AFTER allocation (decode error mid-import) must hand the
-    fresh pages back before propagating."""
-    from repro.serving import engine as eng_mod
+    """A failure AFTER allocation (a device error in the scatter) must
+    hand the fresh pages back before propagating as it is."""
     from repro.serving.engine import RealEngine
     cfg, model, params = gt
     _, buf, chains = _seeded_export(gt)
     dst = RealEngine(cfg, model, params, max_len=128)
     free0 = dst.allocator.free_count
 
-    def boom(rec, dtype=None):
-        raise RuntimeError("corrupt wire payload")
-    monkeypatch.setattr(eng_mod, "decompress_kv_blocks", boom)
-    with pytest.raises(RuntimeError):
+    def boom(arena, pages, blocks):
+        assert dst.allocator.free_count == free0 - len(pages)
+        raise RuntimeError("device fault in scatter")
+    monkeypatch.setattr(dst, "_scatter_pages", boom)
+    with pytest.raises(RuntimeError, match="device fault"):
         dst.import_pages(buf, chains)
     assert dst.allocator.free_count == free0
     assert dst.prefix_cache.peek(SHARED) == (0, None)

@@ -8,8 +8,8 @@ from repro.configs import base
 from repro.distributed import sharding
 from repro.models.lm import build_model
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", base.ASSIGNED)
