@@ -274,7 +274,8 @@ def one_chip(cfg, seed, device):
         check(len(r.output) == MAX_NEW,
               f"request {r.req_id}: {MAX_NEW} tokens delivered")
         log(f"request {r.req_id}: prompt={r.prompt_tokens} "
-            f"cached={r.cached_tokens} ttft_s={r.ttft!r} wall_s={r.total!r} "
+            f"cached={r.cached_tokens} queued_s={r.queued_s!r} "
+            f"ttft_s={r.ttft!r} wall_s={r.total!r} "
             f"tokens={r.output}")
     check(len(shared) == 1 and shared[0].cached_tokens >= SHARED_LEN,
           f"shared-prefix request reused >= {SHARED_LEN} cached tokens")

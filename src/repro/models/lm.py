@@ -256,19 +256,26 @@ def layer_decode_paged(cfg, spec, p, x, pos, arena, page_table,
     {"k","v"}: (P, BLOCK, nkv, h); page_table: (B, n_pg); pos: (B,).
     ``write=False`` skips the arena scatter (query-only replay over fully
     cached tokens — never mutate pages another request may alias)."""
-    h = common.apply_norm(cfg, p["norm1"], x)
-    q, k, v = attention.project_qkv(cfg, p["mixer"], h, pos[:, None],
-                                    rope=True)
+    with jax.named_scope("qkv"):
+        h = common.apply_norm(cfg, p["norm1"], x)
+        q, k, v = attention.project_qkv(cfg, p["mixer"], h, pos[:, None],
+                                        rope=True)
     ka, va = arena["k"], arena["v"]
     if write:
-        ka, va = attention.update_paged_cache(ka, va, k, v, page_table, pos)
-    o = attention.paged_decode_attention(cfg, q, ka, va, page_table, pos,
-                                         window=spec.window, active=active)
-    h = attention.out_proj(cfg, p["mixer"], o)
-    if cfg.double_norm:
-        h = common.apply_norm(cfg, p["norm1b"], h)
-    x = x + h
-    x, _ = _ffn_block(cfg, spec, p, x)
+        with jax.named_scope("kv_write"):
+            ka, va = attention.update_paged_cache(ka, va, k, v, page_table,
+                                                  pos)
+    with jax.named_scope("attention"):
+        o = attention.paged_decode_attention(cfg, q, ka, va, page_table,
+                                             pos, window=spec.window,
+                                             active=active)
+    with jax.named_scope("out_proj"):
+        h = attention.out_proj(cfg, p["mixer"], o)
+        if cfg.double_norm:
+            h = common.apply_norm(cfg, p["norm1b"], h)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x, _ = _ffn_block(cfg, spec, p, x)
     return x, {"k": ka, "v": va}
 
 
@@ -287,22 +294,27 @@ def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
     ka, va = arena["k"], arena["v"]
     blk = ka.shape[1]
     positions = pos0[:, None] + jnp.arange(C)[None]
-    h = common.apply_norm(cfg, p["norm1"], x)
-    q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
-                                    rope=True)
-    phys = page_table[jnp.arange(B), pos0 // blk]      # (B,)
-    if active is not None:
-        phys = jnp.where(active, phys, 0)              # dead rows -> scratch
-    ka = ka.at[phys].set(k)
-    va = va.at[phys].set(v)
-    o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                          positions, window=spec.window,
-                                          block_q=block_q, active=active)
-    h = attention.out_proj(cfg, p["mixer"], o)
-    if cfg.double_norm:
-        h = common.apply_norm(cfg, p["norm1b"], h)
-    x = x + h
-    x, _ = _ffn_block(cfg, spec, p, x)
+    with jax.named_scope("qkv"):
+        h = common.apply_norm(cfg, p["norm1"], x)
+        q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
+                                        rope=True)
+    with jax.named_scope("kv_write"):
+        phys = page_table[jnp.arange(B), pos0 // blk]      # (B,)
+        if active is not None:
+            phys = jnp.where(active, phys, 0)          # dead rows -> scratch
+        ka = ka.at[phys].set(k)
+        va = va.at[phys].set(v)
+    with jax.named_scope("attention"):
+        o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
+                                              positions, window=spec.window,
+                                              block_q=block_q, active=active)
+    with jax.named_scope("out_proj"):
+        h = attention.out_proj(cfg, p["mixer"], o)
+        if cfg.double_norm:
+            h = common.apply_norm(cfg, p["norm1b"], h)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x, _ = _ffn_block(cfg, spec, p, x)
     return x, {"k": ka, "v": va}
 
 
@@ -325,20 +337,25 @@ def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None):
     the committed position until it is overwritten."""
     B, W, _ = x.shape
     positions = pos[:, None] + jnp.arange(W)[None]
-    h = common.apply_norm(cfg, p["norm1"], x)
-    q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
-                                    rope=True)
-    ka, va = attention.update_paged_cache_window(
-        arena["k"], arena["v"], k, v, page_table, pos, n_tok=n_tok)
+    with jax.named_scope("qkv"):
+        h = common.apply_norm(cfg, p["norm1"], x)
+        q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
+                                        rope=True)
+    with jax.named_scope("kv_write"):
+        ka, va = attention.update_paged_cache_window(
+            arena["k"], arena["v"], k, v, page_table, pos, n_tok=n_tok)
     active = None if n_tok is None else n_tok > 0
-    o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                          positions, window=spec.window,
-                                          block_q=64, active=active)
-    h = attention.out_proj(cfg, p["mixer"], o)
-    if cfg.double_norm:
-        h = common.apply_norm(cfg, p["norm1b"], h)
-    x = x + h
-    x, _ = _ffn_block(cfg, spec, p, x)
+    with jax.named_scope("attention"):
+        o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
+                                              positions, window=spec.window,
+                                              block_q=64, active=active)
+    with jax.named_scope("out_proj"):
+        h = attention.out_proj(cfg, p["mixer"], o)
+        if cfg.double_norm:
+            h = common.apply_norm(cfg, p["norm1b"], h)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x, _ = _ffn_block(cfg, spec, p, x)
     return x, {"k": ka, "v": va}
 
 
@@ -620,7 +637,8 @@ class LM:
         cfg = self.cfg
         B, C = tokens.shape
         positions = pos0[:, None] + jnp.arange(C)[None]
-        x = self._embed(params, tokens, positions)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, positions)
 
         def body(x, xs):
             bp, ar = xs
@@ -633,8 +651,9 @@ class LM:
             return constraints.constrain_batch(x), tuple(new)
 
         x, arena = jax.lax.scan(body, x, (params["blocks"], arena))
-        x = common.apply_norm(cfg, params["final_norm"], x)
-        return self._logits(params, x), arena
+        with jax.named_scope("logits"):
+            x = common.apply_norm(cfg, params["final_norm"], x)
+            return self._logits(params, x), arena
 
     def decode_paged(self, params, arena, page_tables, tokens, pos,
                      active=None, write=True):
@@ -643,7 +662,8 @@ class LM:
         ``write=False`` the arena is returned untouched (query-only replay
         for full prefix hits — aliased pages are never mutated)."""
         cfg = self.cfg
-        x = self._embed(params, tokens, pos[:, None])
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, pos[:, None])
 
         def body(x, xs):
             bp, ar = xs
@@ -656,8 +676,9 @@ class LM:
             return constraints.constrain_batch(x), tuple(new)
 
         x, arena = jax.lax.scan(body, x, (params["blocks"], arena))
-        x = common.apply_norm(cfg, params["final_norm"], x)
-        logits = self._logits(params, x[:, -1])
+        with jax.named_scope("logits"):
+            x = common.apply_norm(cfg, params["final_norm"], x)
+            logits = self._logits(params, x[:, -1])
         return logits, arena
 
     def verify_paged(self, params, arena, page_tables, tokens, pos,
@@ -677,7 +698,8 @@ class LM:
         cfg = self.cfg
         B, W = tokens.shape
         positions = pos[:, None] + jnp.arange(W)[None]
-        x = self._embed(params, tokens, positions)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, positions)
 
         def body(x, xs):
             bp, ar = xs
@@ -689,8 +711,9 @@ class LM:
             return constraints.constrain_batch(x), tuple(new)
 
         x, arena = jax.lax.scan(body, x, (params["blocks"], arena))
-        x = common.apply_norm(cfg, params["final_norm"], x)
-        return self._logits(params, x), arena
+        with jax.named_scope("logits"):
+            x = common.apply_norm(cfg, params["final_norm"], x)
+            return self._logits(params, x), arena
 
     # ---------------- cache scaffolding ----------------
     def cache_zeros(self, B, max_len, T_mem=0):

@@ -17,7 +17,6 @@ on the reduced config (see benchmarks/bench_serving_latency.py).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -30,6 +29,7 @@ from repro.models.lm import (arena_gather_pages, arena_scatter_pages,
                              cache_slot_read, cache_slot_write)
 from repro.serving.page_pool import OutOfPages, PageAllocator, PagedHandle
 from repro.serving.prefix_cache import BLOCK, PrefixCache
+from repro.serving.trace import CLOCK, Recorder
 from repro.training.compression import (compress_kv_blocks,
                                         decompress_kv_blocks)
 
@@ -46,10 +46,15 @@ class Request:
 
 @dataclass
 class Result:
+    """A finished request.  ``ttft`` and ``total`` are the seconds to its
+    first token (read to the host) and to its finish, from
+    ``Scheduler.submit`` (or from the call of ``generate``); ``queued_s``
+    is submit -> admission (0 for ``generate``)."""
     req_id: int
     output: list
     ttft: float = 0.0
     total: float = 0.0
+    queued_s: float = 0.0
     cached_tokens: int = 0
     prompt_tokens: int = 0
 
@@ -138,6 +143,10 @@ class RealEngine:
         self.batched_prefill_traces = 0   # compilations of batched admission
         self.prefill_dispatches = 0       # jitted prefill_paged calls issued
         self.prefill_tokens = 0           # real (non-pad) tokens prefilled
+        self.prefill_slots = 0            # grid rows x BLOCK dispatched
+        self.pages_evicted = 0            # pages freed by allocator pressure
+        # spans and step records of this node (serving/trace.py)
+        self.recorder = Recorder()
         # speculative decode counters (scheduler-driven verify rounds)
         self.spec_traces = 0      # compilations of the batched verify
         self.spec_dispatches = 0  # verify_paged dispatches issued
@@ -149,8 +158,6 @@ class RealEngine:
         # cross-node KV page migration counters (overlay Replicator)
         self.kv_exported_pages = 0   # pages shipped to fetching peers
         self.kv_imported_pages = 0   # pages scattered in from peers
-        self.kv_export_events = 0
-        self.kv_import_events = 0
         # wire codec for exported pages (training/compression.py):
         # "fp16" | "int8" | "raw".  fp16 halves f32 arenas; 16-bit arenas
         # (bf16) ship raw — same bytes, and a bf16 -> fp16 cast would
@@ -271,7 +278,11 @@ class RealEngine:
             try:
                 return self.allocator.alloc(n)
             except OutOfPages:
-                if not self.prefix_cache.pop_lru():
+                free = self.allocator.free_count
+                with self.recorder.span("pages.evict"):
+                    popped = self.prefix_cache.pop_lru()
+                self.pages_evicted += self.allocator.free_count - free
+                if not popped:
                     raise
 
     def release_pages(self, pages):
@@ -303,6 +314,18 @@ class RealEngine:
         self.prefix_cache.insert(full_tokens, handle,
                                  n_cov * self.page_bytes)
 
+    def counters(self) -> dict:
+        """The counters and gauges that the recorder's step records keep:
+        those its readers use (the prefill grid's fill, the pool's pages
+        in use) and the pool's evictions."""
+        c = {"prefill_tokens": self.prefill_tokens,
+             "prefill_slots": self.prefill_slots}
+        if self.paged:
+            c.update(pages_used=self.allocator.used_count,
+                     pages_total=self.num_pages - 1,
+                     pages_evicted=self.pages_evicted)
+        return c
+
     def live_kv_bytes(self) -> int:
         """Physical KV footprint: pages in use x bytes per page (aliased
         pages counted once — the point of the paged pool)."""
@@ -333,7 +356,6 @@ class RealEngine:
         layers = [{n: compress_kv_blocks(layer[n], mode) for n in ("k", "v")}
                   for layer in gathered]
         self.kv_exported_pages += len(pages)
-        self.kv_export_events += 1
         return {"n_pages": len(pages), "mode": mode, "layers": layers}
 
     def import_pages(self, buf: dict, chains: list) -> PagedHandle:
@@ -388,7 +410,6 @@ class RealEngine:
         self.prefix_cache.insert_chains(chains, handle,
                                         n * self.page_bytes)
         self.kv_imported_pages += n
-        self.kv_import_events += 1
         return handle
 
     # ------------------------------------------------------------------
@@ -482,12 +503,15 @@ class RealEngine:
             # position mask exposes it
             chunk = toks[pos:pos + blk]
             buf = chunk + [0] * (blk - len(chunk))
-            pt = jnp.asarray(self.page_table_row(pages))
-            logits, self.arena = self._prefill_paged(
-                self.params, self.arena, pt,
-                jnp.asarray([buf], jnp.int32), jnp.asarray([pos], jnp.int32))
+            with self.recorder.span("engine.prefill_dispatch", rows=1):
+                pt = jnp.asarray(self.page_table_row(pages))
+                logits, self.arena = self._prefill_paged(
+                    self.params, self.arena, pt,
+                    jnp.asarray([buf], jnp.int32),
+                    jnp.asarray([pos], jnp.int32))
             self.prefill_dispatches += 1
             self.prefill_tokens += len(chunk)
+            self.prefill_slots += blk
             logits_last = logits[:, len(chunk) - 1]
             pos += len(chunk)
         return pos, logits_last
@@ -526,28 +550,33 @@ class RealEngine:
             n_steps = max((len(r["toks"]) - r["pos"] + blk - 1) // blk
                           for r in rows)
             for _ in range(n_steps):
-                tok = np.zeros((B, blk), np.int32)
-                pos0 = np.zeros((B,), np.int32)
-                act = np.zeros((B,), bool)
-                ptab = np.zeros((B, self.max_pages), np.int32)
-                ends = []                    # rows finishing this step
-                for i, r in enumerate(rows):
-                    if r["pos"] >= len(r["toks"]):
-                        continue             # suffix done: masked this step
-                    r["pages"].extend(self.alloc_pages(1))
-                    chunk = r["toks"][r["pos"]:r["pos"] + blk]
-                    tok[i, :len(chunk)] = chunk
-                    pos0[i] = r["pos"]
-                    act[i] = True
-                    ptab[i, :len(r["pages"])] = r["pages"]
-                    if r["pos"] + len(chunk) >= len(r["toks"]):
-                        ends.append((i, len(chunk)))
-                    r["pos"] += len(chunk)
-                    self.prefill_tokens += len(chunk)
-                logits, self.arena = self._prefill_paged_batched(
-                    self.params, self.arena, jnp.asarray(ptab),
-                    jnp.asarray(tok), jnp.asarray(pos0), jnp.asarray(act))
+                live = sum(r["pos"] < len(r["toks"]) for r in rows)
+                with self.recorder.span("engine.prefill_dispatch",
+                                        rows=live):
+                    tok = np.zeros((B, blk), np.int32)
+                    pos0 = np.zeros((B,), np.int32)
+                    act = np.zeros((B,), bool)
+                    ptab = np.zeros((B, self.max_pages), np.int32)
+                    ends = []                # rows finishing this step
+                    for i, r in enumerate(rows):
+                        if r["pos"] >= len(r["toks"]):
+                            continue         # suffix done: masked
+                        r["pages"].extend(self.alloc_pages(1))
+                        chunk = r["toks"][r["pos"]:r["pos"] + blk]
+                        tok[i, :len(chunk)] = chunk
+                        pos0[i] = r["pos"]
+                        act[i] = True
+                        ptab[i, :len(r["pages"])] = r["pages"]
+                        if r["pos"] + len(chunk) >= len(r["toks"]):
+                            ends.append((i, len(chunk)))
+                        r["pos"] += len(chunk)
+                        self.prefill_tokens += len(chunk)
+                    logits, self.arena = self._prefill_paged_batched(
+                        self.params, self.arena, jnp.asarray(ptab),
+                        jnp.asarray(tok), jnp.asarray(pos0),
+                        jnp.asarray(act))
                 self.prefill_dispatches += 1
+                self.prefill_slots += B * blk
                 for i, off in ends:
                     rows[i]["logits"] = logits[i:i + 1, off - 1]
         except BaseException:
@@ -571,13 +600,14 @@ class RealEngine:
         """One-slot sequential decode (thin wrapper over prefill_request)."""
         if self.paged:
             return self._generate_paged(req)
-        t0 = time.monotonic()
+        t0 = CLOCK()
         st = self.prefill_request(req)
         cache, logits, pos = st.cache, st.logits, st.pos
-        ttft = time.monotonic() - t0
-        out = []
+        ttft, out = None, []
         for _ in range(req.max_new):
             nxt = int(jnp.argmax(logits[0]))
+            if ttft is None:
+                ttft = CLOCK() - t0
             out.append(nxt)
             if nxt == req.eos_id or pos >= self.max_len - 1:
                 break
@@ -590,20 +620,18 @@ class RealEngine:
         # pos counts exactly the tokens whose state is in the cache
         full = ([int(t) for t in req.tokens] + out)[:pos]
         self.prefix_cache.insert(full, cache, self._cache_nbytes(cache))
-        return Result(req.req_id, out, ttft=ttft,
-                      total=time.monotonic() - t0,
-                      cached_tokens=st.matched,
-                      prompt_tokens=len(req.tokens))
+        return self._result(req, out, t0, ttft, st.matched)
 
     def _generate_paged(self, req: Request) -> Result:
-        t0 = time.monotonic()
+        t0 = CLOCK()
         st = self.prefill_request(req)
         pages, logits, pos = st.pages, st.logits, st.pos
-        ttft = time.monotonic() - t0
-        out = []
+        ttft, out = None, []
         try:
             for _ in range(req.max_new):
                 nxt = int(jnp.argmax(logits[0]))
+                if ttft is None:
+                    ttft = CLOCK() - t0
                 out.append(nxt)
                 if nxt == req.eos_id or pos >= self.max_len - 1:
                     break
@@ -618,9 +646,14 @@ class RealEngine:
             self.insert_prefix(full, pages)  # zero-copy (page refs)
         finally:
             self.release_pages(pages)        # request's own reference
-        return Result(req.req_id, out, ttft=ttft,
-                      total=time.monotonic() - t0,
-                      cached_tokens=st.matched,
+        return self._result(req, out, t0, ttft, st.matched)
+
+    @staticmethod
+    def _result(req: Request, out: list, t0: float, ttft, matched: int):
+        """``generate``'s Result; with no token, ``ttft`` is the total."""
+        total = CLOCK() - t0
+        return Result(req.req_id, out, ttft=total if ttft is None else ttft,
+                      total=total, cached_tokens=matched,
                       prompt_tokens=len(req.tokens))
 
 

@@ -28,7 +28,6 @@ every admission.
 from __future__ import annotations
 
 import collections
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -37,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.engine import NgramDrafter, RealEngine, Request, Result
+from repro.serving.trace import CLOCK
 
 
 @dataclass
@@ -44,8 +44,9 @@ class _Slot:
     req: Request
     pos: int
     out: list = field(default_factory=list)
-    t_start: float = 0.0
-    ttft: float = 0.0
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0       # first token, once there is one
     cached_tokens: int = 0
     pages: list = field(default_factory=list)   # paged engines only
     drafter: object = None                      # NgramDrafter (spec mode)
@@ -55,6 +56,7 @@ class _Slot:
 class _Queued:
     req: Request
     hint: int           # block-aligned prefix-cache match length at submit
+    t_submit: float
 
 
 class Scheduler:
@@ -66,8 +68,8 @@ class Scheduler:
         self.queue: collections.deque = collections.deque()
         self.slots: list[Optional[_Slot]] = [None] * max_active
         self.done: list[Result] = []
-        self.metrics = {"admitted": 0, "completed": 0, "queue_peak": 0,
-                        "decode_calls": 0, "rounds": 0}
+        self.metrics = {"admitted": 0, "completed": 0, "decode_calls": 0}
+        self.rec = engine.recorder
         # speculative n-gram decode: one multi-token verify dispatch per
         # round instead of the one-token pool decode (paged engines only;
         # cfg.spec_enabled/spec_k are serving policy, not arch traits)
@@ -95,16 +97,22 @@ class Scheduler:
         return [s for s in self.slots if s is not None]
 
     def submit(self, req: Request):
+        t_submit = CLOCK()
         hint = 0
         if self.prefer_cache_hits:
-            hint, _ = self.engine.prefix_cache.peek(
-                [int(t) for t in req.tokens])
-        self.queue.append(_Queued(req, hint))
-        self.metrics["queue_peak"] = max(self.metrics["queue_peak"],
-                                         len(self.queue))
+            with self.rec.span("prefix.peek", rid=req.req_id):
+                hint, _ = self.engine.prefix_cache.peek(
+                    [int(t) for t in req.tokens])
+        self.queue.append(_Queued(req, hint, t_submit))
+
+    def counters(self) -> dict:
+        """The engine's counter snapshot with the scheduler's active
+        rows."""
+        return {**self.engine.counters(),
+                "active": sum(s is not None for s in self.slots)}
 
     # ------------------------------------------------------------------
-    def _pick_request(self) -> Request:
+    def _pick_request(self) -> _Queued:
         ix = 0
         if self.prefer_cache_hits and len(self.queue) > 1:
             best, best_len = 0, -1
@@ -114,30 +122,30 @@ class Scheduler:
             ix = best
         q = self.queue[ix]
         del self.queue[ix]
-        return q.req
+        return q
 
     def _admit_one(self):
         free = next((i for i, s in enumerate(self.slots) if s is None), None)
         if free is None or not self.queue:
             return
-        req = self._pick_request()
-        t0 = time.monotonic()
-        eng = self.engine
-        st = eng.prefill_request(req)
-        if eng.paged:
-            # zero-copy admission: the slot row IS the page table — shared
-            # prefix pages alias the cache holder's pages (refcounted)
-            self._ptab[free, :] = 0
-            self._ptab[free, :len(st.pages)] = st.pages
-        else:
-            self._cache = eng._slot_write(self._cache, st.cache, free)
-        self._logits = self._logits.at[free].set(st.logits[0])
-        self.slots[free] = _Slot(req, st.pos, t_start=t0,
-                                 ttft=time.monotonic() - t0,
-                                 cached_tokens=st.matched,
-                                 pages=st.pages or [],
-                                 drafter=self._new_drafter(req))
-        self.metrics["admitted"] += 1
+        with self.rec.span("sched.admit"):
+            q = self._pick_request()
+            t = CLOCK()
+            eng = self.engine
+            st = eng.prefill_request(q.req)
+            if eng.paged:
+                # zero-copy admission: the slot row IS the page table —
+                # shared prefix pages alias the cache holder's pages
+                self._ptab[free, :] = 0
+                self._ptab[free, :len(st.pages)] = st.pages
+            else:
+                self._cache = eng._slot_write(self._cache, st.cache, free)
+            self._logits = self._logits.at[free].set(st.logits[0])
+            self.slots[free] = _Slot(q.req, st.pos, t_submit=q.t_submit,
+                                     t_admit=t, cached_tokens=st.matched,
+                                     pages=st.pages or [],
+                                     drafter=self._new_drafter(q.req))
+            self.metrics["admitted"] += 1
 
     def _admit_batch(self):
         """Paged admission for a whole round: drain the queue into every
@@ -145,26 +153,26 @@ class Scheduler:
         (engine.prefill_requests) — K admitted requests cost max(chunks)
         dispatches on a shared grid instead of K separate chunk loops."""
         free = [i for i, s in enumerate(self.slots) if s is None]
-        picked = []
-        for slot in free:
-            if not self.queue:
-                break
-            picked.append((slot, self._pick_request()))
-        if not picked:
+        if not free or not self.queue:
             return
-        t0 = time.monotonic()
-        states = self.engine.prefill_requests(
-            [req for _, req in picked], batch=self.max_active)
-        ttft = time.monotonic() - t0
-        for (slot, req), st in zip(picked, states):
-            self._ptab[slot, :] = 0
-            self._ptab[slot, :len(st.pages)] = st.pages
-            self._logits = self._logits.at[slot].set(st.logits[0])
-            self.slots[slot] = _Slot(req, st.pos, t_start=t0, ttft=ttft,
-                                     cached_tokens=st.matched,
-                                     pages=st.pages or [],
-                                     drafter=self._new_drafter(req))
-            self.metrics["admitted"] += 1
+        with self.rec.span("sched.admit"):
+            picked = []
+            for slot in free:
+                if not self.queue:
+                    break
+                picked.append((slot, self._pick_request()))
+            t = CLOCK()
+            states = self.engine.prefill_requests(
+                [q.req for _, q in picked], batch=self.max_active)
+            for (slot, q), st in zip(picked, states):
+                self._ptab[slot, :] = 0
+                self._ptab[slot, :len(st.pages)] = st.pages
+                self._logits = self._logits.at[slot].set(st.logits[0])
+                self.slots[slot] = _Slot(q.req, st.pos, t_submit=q.t_submit,
+                                         t_admit=t, cached_tokens=st.matched,
+                                         pages=st.pages or [],
+                                         drafter=self._new_drafter(q.req))
+                self.metrics["admitted"] += 1
 
     def _new_drafter(self, req: Request):
         return NgramDrafter(req.tokens) if self.spec else None
@@ -172,7 +180,13 @@ class Scheduler:
     # ------------------------------------------------------------------
     def step(self):
         """One continuous-batching round: admit into free slots, then ONE
-        batched decode dispatch for every still-active slot."""
+        batched decode dispatch for every still-active slot.  The round is
+        the recorder's span ``sched.step``; its record closes with the
+        node's counters."""
+        with self.rec.step(self.counters):
+            self._round()
+
+    def _round(self):
         if self.engine.paged:
             self._admit_batch()
         else:
@@ -181,14 +195,18 @@ class Scheduler:
         active_ix = [i for i, s in enumerate(self.slots) if s is not None]
         if not active_ix:
             return
-        self.metrics["rounds"] += 1
-        nxt = np.asarray(jnp.argmax(self._logits, axis=-1))
+        with self.rec.span("sched.read_tokens"):
+            # the host waits here for the previous round's decode
+            nxt = np.asarray(jnp.argmax(self._logits, axis=-1))
+        now = CLOCK()
         finished, cont = [], []
         for i in active_ix:
             s = self.slots[i]
             tok = int(nxt[i])
             if len(s.out) < s.req.max_new:     # max_new=0 emits nothing,
-                s.out.append(tok)              # matching generate()
+                if not s.out:                  # matching generate()
+                    s.t_first = now
+                s.out.append(tok)
             if (tok == s.req.eos_id or len(s.out) >= s.req.max_new
                     or s.pos >= self.engine.max_len - 1):
                 finished.append(i)
@@ -206,7 +224,8 @@ class Scheduler:
         if self.spec:
             drafts = self._collect_drafts(cont)
             if any(drafts.values()):
-                self._verify_round(cont, nxt, drafts)
+                with self.rec.span("sched.verify"):
+                    self._verify_round(cont, nxt, drafts)
                 return
             # no slot drafted: the full (B, W, V) verify window would
             # commit exactly one token per row anyway — issue the cached
@@ -221,27 +240,30 @@ class Scheduler:
         draft-less fallback)."""
         eng = self.engine
         B = self.max_active
-        tok = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        act = np.zeros((B,), bool)
-        for i in cont:
-            tok[i, 0] = nxt[i]
-            pos[i] = self.slots[i].pos
-            act[i] = True
+        with self.rec.span("sched.decode_prep"):
+            tok = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            act = np.zeros((B,), bool)
+            for i in cont:
+                tok[i, 0] = nxt[i]
+                pos[i] = self.slots[i].pos
+                act[i] = True
+                if eng.paged:
+                    # the write position may cross into a new block: grow
+                    # the slot's pages before the single pool dispatch
+                    s = self.slots[i]
+                    eng.ensure_page_for(s.pages, s.pos)
+                    self._ptab[i, :len(s.pages)] = s.pages
+            ins = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(act))
             if eng.paged:
-                # the write position may cross into a new block: grow
-                # the slot's pages before the single pool dispatch
-                s = self.slots[i]
-                eng.ensure_page_for(s.pages, s.pos)
-                self._ptab[i, :len(s.pages)] = s.pages
-        if eng.paged:
-            self._logits, eng.arena = eng._decode_batched(
-                eng.params, eng.arena, jnp.asarray(self._ptab),
-                jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(act))
-        else:
-            self._logits, self._cache = eng._decode_batched(
-                eng.params, self._cache,
-                jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(act))
+                ins = (jnp.asarray(self._ptab),) + ins
+        with self.rec.span("sched.decode_dispatch"):
+            if eng.paged:
+                self._logits, eng.arena = eng._decode_batched(
+                    eng.params, eng.arena, *ins)
+            else:
+                self._logits, self._cache = eng._decode_batched(
+                    eng.params, self._cache, *ins)
         self.metrics["decode_calls"] += 1
         for i in cont:
             self.slots[i].pos += 1
@@ -305,7 +327,8 @@ class Scheduler:
             jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(ntk))
         eng.spec_dispatches += 1
         self.metrics["decode_calls"] += 1
-        greedy = np.asarray(jnp.argmax(logits, axis=-1))      # (B, W)
+        with self.rec.span("sched.read_tokens"):
+            greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (B, W)
         sel = np.zeros((B,), np.int32)     # per-row next-logits window index
         keep = np.zeros((B,), bool)
         finished = []
@@ -342,24 +365,31 @@ class Scheduler:
 
     def _finish_slot(self, i: int):
         s = self.slots[i]
-        self.slots[i] = None
-        eng = self.engine
-        # s.pos counts exactly the tokens whose KV is in the slot (the
-        # finishing token was appended but never pool-decoded) — inserting
-        # more would register block keys over positions that hold nothing
-        full = ([int(t) for t in s.req.tokens] + s.out)[:s.pos]
-        if eng.paged:
-            eng.insert_prefix(full, s.pages)   # by reference, zero copy
-            eng.release_pages(s.pages)
-            self._ptab[i, :] = 0
-        else:
-            kv = eng._slot_read(self._cache, i)
-            eng.prefix_cache.insert(full, kv, eng._cache_nbytes(kv))
-        self.done.append(Result(s.req.req_id, s.out, ttft=s.ttft,
-                                total=time.monotonic() - s.t_start,
-                                cached_tokens=s.cached_tokens,
-                                prompt_tokens=len(s.req.tokens)))
-        self.metrics["completed"] += 1
+        with self.rec.span("sched.retire", rid=s.req.req_id):
+            self.slots[i] = None
+            eng = self.engine
+            # s.pos counts exactly the tokens whose KV is in the slot (the
+            # finishing token was appended but never pool-decoded) —
+            # inserting more would register block keys over positions that
+            # hold nothing
+            full = ([int(t) for t in s.req.tokens] + s.out)[:s.pos]
+            with self.rec.span("prefix.insert"):
+                if eng.paged:
+                    eng.insert_prefix(full, s.pages)  # by reference
+                else:
+                    kv = eng._slot_read(self._cache, i)
+                    eng.prefix_cache.insert(full, kv, eng._cache_nbytes(kv))
+            if eng.paged:
+                eng.release_pages(s.pages)
+                self._ptab[i, :] = 0
+            t = CLOCK()
+            first = s.t_first if s.out else t
+            self.done.append(Result(
+                s.req.req_id, s.out, ttft=first - s.t_submit,
+                total=t - s.t_submit, queued_s=s.t_admit - s.t_submit,
+                cached_tokens=s.cached_tokens,
+                prompt_tokens=len(s.req.tokens)))
+            self.metrics["completed"] += 1
 
     def run(self, max_rounds: int = 10_000):
         rounds = 0
