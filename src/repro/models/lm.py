@@ -288,8 +288,14 @@ def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
 
     ``active``: optional (B,) bool mask (batched admission over a shared
     chunk grid) — inactive rows scatter onto the scratch page 0 instead
-    of a live page, so short-suffix rows never corrupt the arena while
-    longer siblings still have chunks in flight."""
+    of a live page, so empty rows never corrupt the arena.
+
+    Invariant batched admission rests on: ALL rows scatter their K/V
+    into the arena before ANY row attends, in every layer.  Rows holding
+    consecutive chunks of one request (with the same page table) thus
+    read their earlier siblings' K/V of this layer, and the causal and
+    window masks hide the later ones — one dispatch computes exactly
+    what the chunks would compute one after another."""
     B, C, _ = x.shape
     ka, va = arena["k"], arena["v"]
     blk = ka.shape[1]
@@ -630,10 +636,12 @@ class LM:
         later writes before any mask exposes it), plus the updated arena.
 
         ``active`` is an optional (B,) bool mask for batched admission:
-        all admitted requests' divergence suffixes march through ONE
-        shared chunk grid, rows whose suffix already ended are masked
+        the admitted requests' uncached chunks are packed one per row of
+        ONE shared chunk grid (several rows may hold consecutive chunks
+        of one request, each row with the request's whole page table), and
+        rows the last dispatch of an admission leaves empty are masked
         (scratch-page writes, zeroed output) — K co-routed siblings cost
-        max(chunks) dispatches instead of sum(chunks)."""
+        ceil(sum(chunks) / B) dispatches instead of sum(chunks)."""
         cfg = self.cfg
         B, C = tokens.shape
         positions = pos0[:, None] + jnp.arange(C)[None]

@@ -521,17 +521,24 @@ class RealEngine:
     # ------------------------------------------------------------------
     def prefill_requests(self, reqs: list, batch: Optional[int] = None
                          ) -> list:
-        """Batched paged admission: every request's divergence suffix
-        marches through ONE shared BLOCK-chunk grid.
+        """Batched paged admission: every request's uncached chunks are
+        packed into ONE shared ``batch``-row, BLOCK-token grid.
 
-        Per chunk step there is a single ``prefill_paged`` dispatch over a
-        fixed ``batch``-row grid (per-row page tables, per-row block-
-        aligned start positions, masked tail rows for suffixes that ended
-        early), so K co-routed siblings cost ``max(chunks)`` dispatches
-        instead of ``sum(chunks)`` — the per-request admission loop the
-        sequential path still pays.  Prefix hits alias cached pages first
-        exactly like ``prefill_request``; rows whose prompt is fully
-        cached skip the grid and replay their last token query-only.
+        Prefix hits alias cached pages first exactly like
+        ``prefill_request``.  The uncached chunks then queue request
+        after request, and each ``prefill_paged`` dispatch takes the next
+        ``batch`` of them, one per row, so one request may fill several
+        rows.  Each of its rows carries the request's whole page table (a
+        fresh page per chunk, allocated before the dispatch) and starts
+        at its own chunk: every layer scatters all rows' K/V before any
+        row attends, so a later chunk reads its earlier siblings' K/V and
+        the causal (and window) mask hides the later ones — exactly what
+        sequential chunks compute.  K admitted requests cost
+        ``ceil(sum(chunks) / batch)`` dispatches of the one compiled grid;
+        only an admission's last dispatch has masked rows.  A request's
+        logits come from the row and offset of its last chunk; a prompt
+        that is a full block-aligned hit takes no row and replays its
+        last token query-only.
 
         Returns one ``PrefillState`` per request, in input order."""
         assert self.paged, "batched admission requires the paged pool"
@@ -547,29 +554,28 @@ class RealEngine:
                 matched, pages = self._match_and_alias(toks)
                 rows.append({"toks": toks, "pages": pages, "pos": matched,
                              "matched": matched, "logits": None})
-            n_steps = max((len(r["toks"]) - r["pos"] + blk - 1) // blk
-                          for r in rows)
-            for _ in range(n_steps):
-                live = sum(r["pos"] < len(r["toks"]) for r in rows)
+            chunks = [(r, p) for r in rows
+                      for p in range(r["pos"], len(r["toks"]), blk)]
+            for d in range(0, len(chunks), B):
+                grid = chunks[d:d + B]
                 with self.recorder.span("engine.prefill_dispatch",
-                                        rows=live):
+                                        rows=len(grid)):
+                    for r, _ in grid:    # whole tables before any row
+                        r["pages"].extend(self.alloc_pages(1))
                     tok = np.zeros((B, blk), np.int32)
                     pos0 = np.zeros((B,), np.int32)
                     act = np.zeros((B,), bool)
                     ptab = np.zeros((B, self.max_pages), np.int32)
-                    ends = []                # rows finishing this step
-                    for i, r in enumerate(rows):
-                        if r["pos"] >= len(r["toks"]):
-                            continue         # suffix done: masked
-                        r["pages"].extend(self.alloc_pages(1))
-                        chunk = r["toks"][r["pos"]:r["pos"] + blk]
+                    ends = []                # (grid row, last offset, req)
+                    for i, (r, p) in enumerate(grid):
+                        chunk = r["toks"][p:p + blk]
                         tok[i, :len(chunk)] = chunk
-                        pos0[i] = r["pos"]
+                        pos0[i] = p
                         act[i] = True
                         ptab[i, :len(r["pages"])] = r["pages"]
-                        if r["pos"] + len(chunk) >= len(r["toks"]):
-                            ends.append((i, len(chunk)))
-                        r["pos"] += len(chunk)
+                        r["pos"] = p + len(chunk)
+                        if r["pos"] == len(r["toks"]):
+                            ends.append((i, len(chunk), r))
                         self.prefill_tokens += len(chunk)
                     logits, self.arena = self._prefill_paged_batched(
                         self.params, self.arena, jnp.asarray(ptab),
@@ -577,8 +583,8 @@ class RealEngine:
                         jnp.asarray(act))
                 self.prefill_dispatches += 1
                 self.prefill_slots += B * blk
-                for i, off in ends:
-                    rows[i]["logits"] = logits[i:i + 1, off - 1]
+                for i, off, r in ends:
+                    r["logits"] = logits[i:i + 1, off - 1]
         except BaseException:
             for r in rows:           # release aliased + fresh references
                 if r["pages"]:

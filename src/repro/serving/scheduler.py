@@ -13,10 +13,12 @@ crosses a block boundary, and completion registers the row's pages with
 ``PrefixCache`` by reference and drops the request's refcount.  Paged
 admission is itself **batched**: every round drains the queue into all
 free slots through one shared-grid ``prefill_paged`` dispatch stream
-(engine.prefill_requests) — K admitted requests cost max(chunks)
-dispatches, not K chunk loops.  Dense engines (recurrent mixers) keep
-the PR-1 ``(R, max_active, ...)`` cache pool with scatter-on-admit /
-gather-on-finish and per-request admission.
+(engine.prefill_requests) that packs the admitted requests' uncached
+chunks one per grid row — K admitted requests cost
+ceil(sum(chunks) / max_active) dispatches, not K chunk loops.  Dense
+engines (recurrent mixers) keep the PR-1 ``(R, max_active, ...)`` cache
+pool with scatter-on-admit / gather-on-finish and per-request
+admission.
 
 Admission keeps session stickiness semantics and a longest-prefix-match
 preference (the node-local analogue of the HR-tree's group-level cache
@@ -150,8 +152,10 @@ class Scheduler:
     def _admit_batch(self):
         """Paged admission for a whole round: drain the queue into every
         free slot through ONE batched ``prefill_paged`` dispatch stream
-        (engine.prefill_requests) — K admitted requests cost max(chunks)
-        dispatches on a shared grid instead of K separate chunk loops."""
+        (engine.prefill_requests) — the admitted requests' uncached chunks
+        fill the ``max_active``-row grid one per row, so K admitted
+        requests cost ceil(sum(chunks) / max_active) dispatches instead of
+        K separate chunk loops."""
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free or not self.queue:
             return

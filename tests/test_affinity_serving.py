@@ -145,12 +145,13 @@ def test_affinity_multinode_parity_and_fewer_prefill_bytes(gt):
     assert aff_nodes[1].metrics["affinity_hits"] == 3
     assert aff_nodes[1].metrics["forwarded_out"] == 3
     # ...so only the divergence tails were prefilled (seed 72 + 3 x 8),
-    # and the whole sibling round was ONE batched dispatch (72-token seed
-    # = 3 chunk steps, 8-token sibling suffixes = 1 shared step)
+    # and the whole sibling round was ONE batched dispatch (the 72-token
+    # seed's 3 chunks pack into one dispatch of the 4-row grid, the three
+    # 8-token sibling suffixes into one more)
     aff_eng = [n.real_engine for n in aff_nodes]
     assert aff_eng[0].prefill_tokens == 72 + 3 * 8
     assert aff_eng[1].prefill_tokens == 0
-    assert aff_eng[0].prefill_dispatches == 3 + 1
+    assert aff_eng[0].prefill_dispatches == 1 + 1
     # load-only kept siblings on the idle node and re-prefilled the
     # shared prefix from scratch there
     lb_eng = [n.real_engine for n in lb_nodes]

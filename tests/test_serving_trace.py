@@ -71,9 +71,10 @@ def test_spans_nest_and_carry_step_and_rid(gt):
     inserted = [sp for sp in spans if sp.name == "prefix.insert"]
     assert sorted(sp.rid for sp in retired) == [0, 1]
     assert [sp.rid for sp in inserted] == [sp.rid for sp in retired]
-    # one admission of both requests: 2 chunks of the longer prompt
+    # one admission of both requests: their 2 + 2 chunks fill one
+    # dispatch of the 4-row grid
     grid = [sp for sp in spans if sp.name == "engine.prefill_dispatch"]
-    assert len(grid) == eng.prefill_dispatches == 2
+    assert len(grid) == eng.prefill_dispatches == 1
     assert len({sp.step for sp in grid}) == 1
 
 
@@ -122,29 +123,32 @@ def test_step_record_holds_the_counter_snapshot(gt):
     assert last.counters["pages_total"] == eng.num_pages - 1
 
 
-@pytest.mark.parametrize("rows", [1, 4], ids=["one_of_four", "full"])
-def test_prefill_slots_and_tokens_by_hand(gt, rows):
-    """Admission into a 4-row grid of BLOCK-token chunks: 40-token prompts
-    take 2 dispatches, each of 4 x BLOCK slots, whatever the live rows."""
+@pytest.mark.parametrize("rows, n", [(1, 1), (4, 2)],
+                         ids=["one_of_four", "full"])
+def test_prefill_slots_and_tokens_by_hand(gt, rows, n):
+    """Admission into a 4-row grid of BLOCK-token chunks: each 40-token
+    prompt is 2 chunks, packed one per row, so one prompt fills 1
+    dispatch and four fill 2; every dispatch counts 4 x BLOCK slots."""
     cfg, model, params = gt
     eng = RealEngine(cfg, model, params, max_len=128)
     s = Scheduler(eng, max_active=4)
     for i in range(rows):
         s.submit(Request(i, _prompt(cfg, i, 40), max_new=1))
     s.step()
-    assert eng.prefill_dispatches == 2
+    assert eng.prefill_dispatches == n
     assert eng.prefill_tokens == 40 * rows
-    assert eng.prefill_slots == 2 * 4 * BLOCK
+    assert eng.prefill_slots == n * 4 * BLOCK
     c = eng.recorder.steps[-1].counters
-    assert (c["prefill_tokens"], c["prefill_slots"]) == (40 * rows, 256)
+    assert (c["prefill_tokens"], c["prefill_slots"]) == (40 * rows,
+                                                         n * 128)
     rows_meta = [sp for sp in eng.recorder.spans
                  if sp.name == "engine.prefill_dispatch"]
-    assert len(rows_meta) == 2
+    assert len(rows_meta) == n
     # the sequential admission path: one row of BLOCK slots per chunk
     eng.prefill_request(Request(9, _prompt(cfg, 9, 70), max_new=1))
-    assert eng.prefill_dispatches == 2 + 3
+    assert eng.prefill_dispatches == n + 3
     assert eng.prefill_tokens == 40 * rows + 70
-    assert eng.prefill_slots == 256 + 3 * BLOCK
+    assert eng.prefill_slots == n * 128 + 3 * BLOCK
 
 
 def test_pages_evicted_and_used_under_a_small_arena(gt):
@@ -209,7 +213,8 @@ def test_profiler_session_holds_the_programs_spans(gt, tmp_path):
             "sched.read_tokens", "sched.decode_prep",
             "sched.decode_dispatch", "sched.retire", "prefix.insert",
             "prefix.peek", "gc"} <= names
-    assert {"rows": 1} in stats["engine.prefill_dispatch"]
+    # one 40-token prompt: its 2 chunks fill 2 rows of one dispatch
+    assert stats["engine.prefill_dispatch"] == [{"rows": 2}]
     assert {"rid": 1} in stats["sched.retire"]
     assert {"generation": 2} in stats["gc"]
     assert any(sp.name == "gc" for sp in eng.recorder.spans)
