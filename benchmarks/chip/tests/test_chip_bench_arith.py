@@ -15,7 +15,7 @@ import arith  # noqa: E402
 DANUBE = json.loads((HERE / "configs" / "danube-1.8b.json").read_text())
 # Yi-34B at its published widths (huggingface.co/01-ai/Yi-34B), 6 of its
 # 60 layers: a pipeline stage of one 16 GB chip
-YI = {"hidden_size": 7168, "intermediate_size": 20480,
+YI = {"family": "llama", "hidden_size": 7168, "intermediate_size": 20480,
       "num_hidden_layers": 6, "num_attention_heads": 56,
       "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 64000,
       "sliding_window": None, "tie_word_embeddings": False}

@@ -13,7 +13,6 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
 
 import cells  # noqa: E402
-import node  # noqa: E402
 import traffic  # noqa: E402
 
 BENCH = cells.load_benchmark()
@@ -64,10 +63,25 @@ def test_config_matches_program(path):
         if entry["name"] == spec["name"]:
             assert entry["reduced"] == spec["reduced"]
             assert (ROOT / entry["file"]) == path
-    arch = node.arch_config(spec, check=True)
+    fam = cells.family(spec["family"])
+    arch = fam.arch_config(spec, check=True)
     assert arch.n_layers == spec["num_hidden_layers"]
-    assert arch.d_head == spec["head_dim"]
+    for key, field in fam.WIDTHS.items():
+        assert getattr(arch, field) == spec[key], key
     assert arch.dtype == spec["torch_dtype"] == arch.param_dtype
+
+
+@pytest.mark.parametrize("path", sorted((HERE / "configs").glob("*.json")),
+                         ids=lambda p: p.stem)
+def test_config_names_a_family(path):
+    """Every configuration file names the family of its blocks, and the
+    family gives what the harness reads (``cells.py``)."""
+    fam = cells.family(json.loads(path.read_text())["family"])
+    for name in ("groups", "LEAVES", "leaves", "WIDTHS", "arch_config",
+                 "program_params", "layer", "token_flops", "prefill_flops",
+                 "decode_flops", "decode_least_bytes", "weight_bytes",
+                 "kv_bytes_per_token"):
+        assert hasattr(fam, name), name
 
 
 def test_every_metric_names_cells_that_exist():

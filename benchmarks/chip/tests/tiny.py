@@ -1,6 +1,7 @@
 """A configuration and mixes small enough for a CPU test run."""
 SPEC = {
     "name": "tiny", "source": "test", "program_config": "h2o-danube-1.8b",
+    "family": "llama",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 512, "sliding_window": 4096, "rope_theta": 10000.0,

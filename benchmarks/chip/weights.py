@@ -7,10 +7,12 @@ to bfloat16: integer work and a single IEEE multiply, so a leaf made
 inside the one jitted call that builds the whole model and the same leaf
 made alone for one layer of the reference are the same bits.
 
-Layout (a Llama/Mistral decoder without biases): per layer ``attn_norm``
-(d), ``wq`` (d, nq, dh), ``wk``/``wv`` (d, nkv, dh), ``wo`` (nq, dh, d),
-``mlp_norm`` (d), ``w_gate``/``w_up`` (d, ff), ``w_down`` (ff, d); then
-``embed`` (V, d), ``final_norm`` (d), ``lm_head`` (V, d).
+Layout: the configuration's family (``families/<family>.py``) gives the
+ordered layer groups and the leaves of each kind of layer; every family
+has the top leaves ``embed`` (V, d), ``final_norm`` (d) and ``lm_head``
+(V, d).  A leaf's key is ``fold_in(fold_in(base, leaf index), layer)``:
+the leaf index in the family's ``LEAVES`` followed by the top leaves, the
+layer numbered over every group in order (0 for a top leaf).
 """
 from __future__ import annotations
 
@@ -20,43 +22,30 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate",
-                "w_up", "w_down")
+import cells
+
 TOP_LEAVES = ("embed", "final_norm", "lm_head")
 NORM_STD = 0.1      # norm scales: 1 + uniform noise of this std
 EMBED_STD = 1.0     # rows of unit RMS, so the norm's epsilon is immaterial
 _HALF = 1 << 15
 
 
-def dims(spec: dict) -> dict:
-    """Model sizes of a configuration file, by short name."""
-    return {"d": spec["hidden_size"], "ff": spec["intermediate_size"],
-            "nq": spec["num_attention_heads"],
-            "nkv": spec["num_key_value_heads"], "dh": spec["head_dim"],
-            "V": spec["vocab_size"], "L": spec["num_hidden_layers"]}
+def top_leaves(spec: dict) -> dict:
+    """``name -> (shape, std, is_norm)`` of the leaves outside the
+    layers."""
+    d, V = spec["hidden_size"], spec["vocab_size"]
+    return {"embed": ((V, d), EMBED_STD, False),
+            "final_norm": ((d,), NORM_STD, True),
+            "lm_head": ((V, d), 1.0 / math.sqrt(d), False)}
 
 
-def leaf_shapes(spec: dict) -> dict:
-    """``name -> (shape, std, is_norm)`` of every leaf; layer leaves are
-    the shapes of one layer."""
-    m = dims(spec)
-    d, ff, nq, nkv, dh, V = (m[k] for k in ("d", "ff", "nq", "nkv", "dh",
-                                            "V"))
-    fan = 1.0 / math.sqrt(d)
-    return {
-        "attn_norm": ((d,), NORM_STD, True),
-        "wq": ((d, nq, dh), fan, False),
-        "wk": ((d, nkv, dh), fan, False),
-        "wv": ((d, nkv, dh), fan, False),
-        "wo": ((nq, dh, d), 1.0 / math.sqrt(nq * dh), False),
-        "mlp_norm": ((d,), NORM_STD, True),
-        "w_gate": ((d, ff), fan, False),
-        "w_up": ((d, ff), fan, False),
-        "w_down": ((ff, d), 1.0 / math.sqrt(ff), False),
-        "embed": ((V, d), EMBED_STD, False),
-        "final_norm": ((d,), NORM_STD, True),
-        "lm_head": ((V, d), fan, False),
-    }
+def layer_groups(spec: dict) -> list:
+    """``[(kind, range of global layer numbers), ...]`` in order."""
+    out, start = [], 0
+    for kind, count in cells.family(spec["family"]).groups(spec):
+        out.append((kind, range(start, start + count)))
+        start += count
+    return out
 
 
 def base_key(seed: int):
@@ -78,32 +67,34 @@ def _leaf(key, shape, std, is_norm):
     return (ints.astype(jnp.float32) * jnp.float32(step)).astype(jnp.bfloat16)
 
 
-def _leaf_key(key, name, layer=0):
-    ix = (LAYER_LEAVES + TOP_LEAVES).index(name)
+def _leaf_key(spec, key, name, layer=0):
+    order = cells.family(spec["family"]).LEAVES + TOP_LEAVES
+    ix = order.index(name)
     return jax.random.fold_in(jax.random.fold_in(key, ix), layer)
 
 
-def _layer(spec, key, layer):
-    shapes = leaf_shapes(spec)
-    return {n: _leaf(_leaf_key(key, n, layer), *shapes[n])
-            for n in LAYER_LEAVES}
+def _layer(spec, kind, key, layer):
+    shapes = cells.family(spec["family"]).leaves(spec, kind)
+    return {n: _leaf(_leaf_key(spec, key, n, layer), *shape)
+            for n, shape in shapes.items()}
 
 
 def _top(spec, key, name):
-    return _leaf(_leaf_key(key, name), *leaf_shapes(spec)[name])
+    return _leaf(_leaf_key(spec, key, name), *top_leaves(spec)[name])
 
 
 def make_params(spec: dict, seed: int, device) -> dict:
     """Every weight of the model in bfloat16 on ``device``, in one jitted
-    call: ``{"layers": {name: (L, ...)}, "embed": ..., "final_norm": ...,
-    "lm_head": ...}``.  Layers are made one after another (``lax.map``),
-    so the call's temporaries are one layer's."""
-    L = dims(spec)["L"]
-
+    call: ``{"layers": [{name: (count, ...)} per layer group],
+    "embed": ..., "final_norm": ..., "lm_head": ...}``.  Each group's
+    layers are made one after another (``lax.map``), so the call's
+    temporaries are one layer's."""
     def gen(key):
         out = {n: _top(spec, key, n) for n in TOP_LEAVES}
-        out["layers"] = jax.lax.map(lambda i: _layer(spec, key, i),
-                                    jnp.arange(L))
+        out["layers"] = [
+            jax.lax.map(lambda i, kind=kind: _layer(spec, kind, key, i),
+                        jnp.arange(layers.start, layers.stop))
+            for kind, layers in layer_groups(spec)]
         return out
 
     fn = jax.jit(gen, out_shardings=SingleDeviceSharding(device))
@@ -116,12 +107,15 @@ class LeafMaker:
 
     def __init__(self, spec: dict, seed: int):
         self.key = base_key(seed)
-        self._layer = jax.jit(lambda k, i: _layer(spec, k, i))
+        self._layer = {kind: jax.jit(lambda k, i, kind=kind:
+                                     _layer(spec, kind, k, i))
+                       for kind, _ in layer_groups(spec)}
         self._top = {n: jax.jit(lambda k, n=n: _top(spec, k, n))
                      for n in TOP_LEAVES}
 
-    def layer(self, i: int) -> dict:
-        return self._layer(self.key, jnp.int32(i))
+    def layer(self, kind: str, i: int) -> dict:
+        """Layer ``i`` (its global number), of kind ``kind``."""
+        return self._layer[kind](self.key, jnp.int32(i))
 
     def top(self, name: str):
         return self._top[name](self.key)
