@@ -11,9 +11,15 @@ hold:
     256-token prefix with an earlier one admits from cached pages;
   * the served paged prefill's last-position logits agree with
     ``LM.apply`` on the same chip;
-  * one decode batch through the Pallas paged kernel (compiled to a
-    ``tpu_custom_call``) agrees with the same batch through jnp;
+  * one decode batch and one packed prefill dispatch through the Pallas
+    paged kernel agree with the same batches through the jnp gather path,
+    and a decode batch reading the wrong pages misses the tolerance;
+  * the node's served decode and prefill programs contain the kernel
+    (``tpu_custom_call``);
   * a page export/import round trip lands the same bytes.
+
+It also prints the share of the page tables the attention visited
+(``attn_pages_read / attn_pages_tabled``).
 
 ``--four-chips`` runs only the overlay's multi-chip shape: four one-chip
 nodes, node i on device i with its own params, a shared-prefix load whose
@@ -180,50 +186,99 @@ def logit_check(engine, model, toks):
                    ref[0, jnp.asarray(lens) - 1, :vocab])
 
 
-def kernel_check(engine, cfg, prompt_list, offsets=(1, 40, 97, 190)):
+def _cached(engine, toks, need):
+    n, entry = engine.prefix_cache.peek(toks)
+    check(n > need, f"kernel check prompt: {n} cached tokens")
+    return n, entry.handle.pages[:n // engine.block]
+
+
+def kernel_check(engine, prompt_list, offsets=(1, 40, 97, 190)):
     """One decode batch (query-only, over cached pages: one row per
     prompt and offset from its cached end) with the Pallas paged kernel
-    and with jnp attention.  The same batch with each prompt's rows
+    and with the jnp gather path.  The same batch with each prompt's rows
     reading another prompt's pages must miss the tolerance, or the check
-    could not see a wrong KV read.  Returns the kernel run's compiled
-    text."""
+    could not see a wrong KV read."""
     import jax
     import numpy as np
-    from repro.models.lm import build_model
+    model, vocab = engine.model, engine.cfg.vocab
     n_off = len(offsets)
     B = len(prompt_list) * n_off
     pt = np.zeros((B, engine.max_pages), np.int32)
     tok = np.zeros((B, 1), np.int32)
     pos = np.zeros((B,), np.int32)
     for i, toks in enumerate(prompt_list):
-        n, entry = engine.prefix_cache.peek(toks)
-        check(n > max(offsets), f"kernel check prompt {i}: {n} cached tokens")
-        pages = entry.handle.pages[:n // engine.block]
+        n, pages = _cached(engine, toks, max(offsets))
         for j, off in enumerate(offsets):
             r = i * n_off + j
             pt[r, :len(pages)] = pages
             tok[r, 0], pos[r] = toks[n - off], n - off
     outs, compiled = {}, {}
-    for use_kernels in (True, False):
-        model = build_model(dataclasses.replace(cfg,
-                                                use_kernels=use_kernels))
-
-        def query(params, arena, pt, tok, pos, model=model):
+    for impl in ("pallas", "ref"):
+        def query(params, arena, pt, tok, pos, impl=impl):
             return model.decode_paged(params, arena, pt, tok, pos,
-                                      write=False)[0][:, :cfg.vocab]
+                                      write=False, impl=impl)[0][:, :vocab]
 
         args = (engine.params, engine.arena, pt, tok, pos)
-        compiled[use_kernels] = jax.jit(query).lower(*args).compile()
-        outs[use_kernels] = compiled[use_kernels](*args)
-    check("tpu_custom_call" not in compiled[False].as_text(),
+        compiled[impl] = jax.jit(query).lower(*args).compile()
+        outs[impl] = compiled[impl](*args)
+    check("tpu_custom_call" not in compiled["ref"].as_text(),
           "jnp decode has no custom call")
-    compare_logits("kernel decode vs jnp decode", outs[True], outs[False])
-    wrong = compiled[False](engine.params, engine.arena,
+    compare_logits("kernel decode vs jnp decode", outs["pallas"],
+                   outs["ref"])
+    wrong = compiled["ref"](engine.params, engine.arena,
                             np.roll(pt, n_off, axis=0), tok, pos)
-    delta = max_delta(wrong, outs[True])
+    delta = max_delta(wrong, outs["pallas"])
     log(f"wrong page table vs kernel decode: max |delta| = {delta!r}")
     check(delta > LOGIT_TOL, "a wrong page table misses the tolerance")
-    return compiled[True].as_text()
+
+
+def prefill_kernel_check(engine, prompt_list, rows=4, starts=(0, 64, 192)):
+    """One packed prefill dispatch of the engine's grid width: chunks of
+    the cached prompts at block-aligned starts re-prefilled over their own
+    pages (the arena is not donated, so the engine's stays as it is), the
+    last row left masked, with the kernel and with the jnp gather path:
+    the logits of every position of every live row agree."""
+    import jax
+    import numpy as np
+    model, vocab, blk = engine.model, engine.cfg.vocab, engine.block
+    grid = list(zip(prompt_list, starts))[:rows - 1]
+    pt = np.zeros((rows, engine.max_pages), np.int32)
+    tok = np.zeros((rows, blk), np.int32)
+    pos0 = np.zeros((rows,), np.int32)
+    act = np.zeros((rows,), bool)
+    for r, (toks, p0) in enumerate(grid):
+        _, pages = _cached(engine, toks, p0 + blk)
+        pt[r, :len(pages)] = pages
+        tok[r], pos0[r], act[r] = toks[p0:p0 + blk], p0, True
+    outs = {}
+    for impl in ("pallas", "ref"):
+        def prefill(params, arena, pt, tok, pos0, act, impl=impl):
+            return model.prefill_paged(params, arena, pt, tok, pos0,
+                                       active=act, impl=impl)[0]
+
+        outs[impl] = jax.jit(prefill)(engine.params, engine.arena, pt, tok,
+                                      pos0, act)[:, :, :vocab]
+    live = np.flatnonzero(act)
+    compare_logits("kernel prefill vs jnp prefill",
+                   np.asarray(outs["pallas"])[live],
+                   np.asarray(outs["ref"])[live])
+
+
+def served_programs_check(engine, rows):
+    """The node's own jitted decode and prefill programs, compiled for
+    the shapes the scheduler serves, contain the paged kernel."""
+    import numpy as np
+    i32 = np.int32
+    pt = np.zeros((rows, engine.max_pages), i32)
+    act = np.zeros((rows,), bool)
+    for name, fn, tok in (
+            ("decode", engine._decode_batched, np.zeros((rows, 1), i32)),
+            ("prefill", engine._prefill_paged_batched,
+             np.zeros((rows, engine.block), i32))):
+        text = fn.lower(engine.params, engine.arena, pt, tok,
+                        np.zeros((rows,), i32), act).compile().as_text()
+        check("tpu_custom_call" in text,
+              f"served {name} program compiled to tpu_custom_call")
 
 
 def page_roundtrip(engine, toks):
@@ -279,10 +334,14 @@ def one_chip(cfg, seed, device):
             f"tokens={r.output}")
     check(len(shared) == 1 and shared[0].cached_tokens >= SHARED_LEN,
           f"shared-prefix request reused >= {SHARED_LEN} cached tokens")
+    log(f"attention visited {eng.attn_pages_read} of "
+        f"{eng.attn_pages_tabled} tabled pages: "
+        f"{eng.attn_pages_read / eng.attn_pages_tabled!r}")
     logit_check(eng, model, ps["p4"])
-    text = kernel_check(eng, cfg, [ps[k] for k in ("p1", "p2", "p3", "p4")])
-    check("tpu_custom_call" in text, "kernel decode compiled to "
-                                     "tpu_custom_call")
+    cached = [ps[k] for k in ("p1", "p2", "p3", "p4")]
+    kernel_check(eng, cached)
+    prefill_kernel_check(eng, cached[1:], rows=node._real_sched.max_active)
+    served_programs_check(eng, node._real_sched.max_active)
     page_roundtrip(eng, ps["p2"][:SHARED_LEN])
 
 

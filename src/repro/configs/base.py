@@ -115,9 +115,6 @@ class ArchConfig:
     dtype: str = "bfloat16"       # activation/compute dtype
     param_dtype: str = "float32"  # master/storage dtype (serve path casts to dtype)
 
-    # kernels (TPU only; dry-run lowers the jnp reference path)
-    use_kernels: bool = False
-
     # speculative decode (serving-time policy, not an architecture trait:
     # no effect on params/init).  With ``spec_enabled`` a paged scheduler
     # verifies up to ``spec_k`` self-drafted n-gram tokens per slot per

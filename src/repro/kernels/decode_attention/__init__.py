@@ -1,2 +1,2 @@
 from repro.kernels.decode_attention.ops import (  # noqa: F401
-    decode_attention, paged_decode_attention)
+    decode_attention, paged_attention)

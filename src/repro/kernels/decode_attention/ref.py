@@ -30,19 +30,35 @@ def decode_attention_ref(q, k, v, lengths, *, window=None, softcap=None,
                       vr.astype(jnp.float32)).astype(q.dtype)
 
 
-def paged_decode_attention_ref(q, k_arena, v_arena, page_table, lengths, *,
-                               window=None, softcap=None, scale=None):
-    """Oracle for the paged kernel: gather pages at the XLA level into the
-    dense per-request layout, then run the dense oracle.  q: (B, H, D);
-    arenas: (P, BLOCK, Hkv, D); page_table: (B, n_pg); lengths: (B,)."""
-    B = q.shape[0]
+def paged_attention_ref(q, k_arena, v_arena, page_table, pos0, lengths, *,
+                        window=None, softcap=None, scale=None):
+    """Oracle for the paged kernel, in f32: gather each row's pages into
+    a dense view at the XLA level and attend with a per-query causal
+    mask.  q: (B, C, H, D) at positions ``pos0[b] + c``; arenas:
+    (P, BLOCK, n_kv * D); page_table: (B, n_pg); lengths: (B,) (0 = dead
+    row, zeros out)."""
+    B, C, H, D = q.shape
     blk = k_arena.shape[1]
+    n_kv = k_arena.shape[2] // D
     n_pg = page_table.shape[1]
+    S = n_pg * blk
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    def dense(arena):
+    def dense(arena):                                   # (B, S, H, D)
         g = jnp.take(arena, page_table.reshape(-1), axis=0)
-        g = g.reshape(B, n_pg * blk, *arena.shape[2:])     # (B, S, Hkv, D)
-        return g.transpose(0, 2, 1, 3)                     # (B, Hkv, S, D)
+        g = g.reshape(B, S, n_kv, D).astype(jnp.float32)
+        return jnp.repeat(g, H // n_kv, axis=2)
 
-    return decode_attention_ref(q, dense(k_arena), dense(v_arena), lengths,
-                                window=window, softcap=softcap, scale=scale)
+    s = jnp.einsum("bchd,bshd->bhcs", q.astype(jnp.float32),
+                   dense(k_arena)) * scale
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    qpos = (pos0[:, None] + jnp.arange(C)[None])[:, None, :, None]
+    kpos = jnp.arange(S)[None, None, None]
+    mask = (kpos <= qpos) & (kpos < lengths[:, None, None, None])
+    if window is not None:
+        mask &= kpos > qpos - window
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    o = jnp.einsum("bhcs,bshd->bchd", p, dense(v_arena))
+    o = jnp.where((lengths > 0)[:, None, None, None], o, 0.0)
+    return o.astype(q.dtype)

@@ -3,10 +3,9 @@
 The full-sequence path is *chunked over query blocks* (lax.scan) so the jnp
 reference path lowered in the dry-run never materializes an (S, S) score
 tensor — same O(S^2) FLOPs as flash attention with O(S * block_q) memory.
-On real TPUs ``cfg.use_kernels`` swaps in the Pallas flash kernel
-(kernels/flash_attention) for this path and kernels/decode_attention for the
-decode path; the dry-run lowers this jnp path (Pallas does not lower for the
-CPU stand-in backend).
+The paged serving paths (the decode step and the prefill grid) run the
+Pallas paged kernel (kernels/decode_attention/paged.py) on a TPU and a jnp
+gather path elsewhere; the full-sequence and dense decode paths are jnp.
 """
 from __future__ import annotations
 
@@ -207,23 +206,45 @@ def update_cache(k_cache, v_cache, kv_positions, k_new, v_new, slot):
 # Paged KV: block-granular arena indexed by per-request page tables
 # --------------------------------------------------------------------------
 #
-# The arena is node-wide: one (num_pages, BLOCK, nkv, h) K and V slab per
-# layer; a request's KV lives in the physical pages its page table names,
+# The arena is node-wide: one (num_pages, BLOCK, nkv * h) K and V slab per
+# layer, so a page is one contiguous run that the paged kernel reads in
+# place; a request's KV lives in the physical pages its page table names,
 # so two requests sharing a prompt prefix alias the same pages instead of
 # holding copies.  Physical page 0 is a scratch page (serving/page_pool):
 # masked slot-pool rows scatter there and never read it back unmasked.
+#
+# ``impl`` picks the attention: "auto" is the Pallas kernel
+# (kernels/decode_attention/paged.py) on a TPU and the jnp gather path
+# elsewhere; "pallas", "interpret" (the kernel in Pallas's interpreter)
+# and "ref" (the jnp gather path) force a side.
 
-def gather_pages(arena, page_table):
-    """arena: (P, BLOCK, nkv, h); page_table: (B, n_pg) int32 physical page
-    per logical block -> (B, n_pg * BLOCK, nkv, h) dense per-request view.
+def _use_kernel(impl: str) -> bool:
+    if impl == "auto":
+        return jax.default_backend() == "tpu"
+    return impl != "ref"
+
+
+def _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos0, lengths,
+                  window, impl):
+    from repro.kernels.decode_attention.paged import paged_attention_pallas
+    return paged_attention_pallas(
+        q, k_arena, v_arena, page_table, pos0, lengths, window=window,
+        softcap=cfg.attn_softcap or None, scale=_scale(cfg),
+        interpret=impl == "interpret")
+
+
+def gather_pages(arena, page_table, n_kv):
+    """arena: (P, BLOCK, nkv * h); page_table: (B, n_pg) int32 physical
+    page per logical block -> (B, n_pg * BLOCK, nkv, h) dense per-request
+    view.
 
     Logical position j of row b lives at arena[page_table[b, j // BLOCK],
     j % BLOCK]; unallocated table entries point at the scratch page and are
     masked by position in the attention that consumes the gather."""
     B, n_pg = page_table.shape
-    blk = arena.shape[1]
+    _, blk, width = arena.shape
     g = jnp.take(arena, page_table.reshape(-1), axis=0)
-    return g.reshape(B, n_pg * blk, *arena.shape[2:])
+    return g.reshape(B, n_pg * blk, n_kv, width // n_kv)
 
 
 def update_paged_cache(k_arena, v_arena, k_new, v_new, page_table, pos):
@@ -236,8 +257,8 @@ def update_paged_cache(k_arena, v_arena, k_new, v_new, page_table, pos):
     bidx = jnp.arange(B)
     phys = page_table[bidx, pos // blk]               # (B,)
     off = pos % blk
-    k_arena = k_arena.at[phys, off].set(k_new[:, 0])
-    v_arena = v_arena.at[phys, off].set(v_new[:, 0])
+    k_arena = k_arena.at[phys, off].set(k_new[:, 0].reshape(B, -1))
+    v_arena = v_arena.at[phys, off].set(v_new[:, 0].reshape(B, -1))
     return k_arena, v_arena
 
 
@@ -263,61 +284,62 @@ def update_paged_cache_window(k_arena, v_arena, k_new, v_new, page_table,
     if n_tok is not None:
         phys = jnp.where(jnp.arange(W)[None] < n_tok[:, None], phys, 0)
     off = positions % blk
-    k_arena = k_arena.at[phys, off].set(k_new)
-    v_arena = v_arena.at[phys, off].set(v_new)
+    k_arena = k_arena.at[phys, off].set(k_new.reshape(B, W, -1))
+    v_arena = v_arena.at[phys, off].set(v_new.reshape(B, W, -1))
     return k_arena, v_arena
 
 
 def paged_decode_attention(cfg, q, k_arena, v_arena, page_table, pos,
-                           window: Optional[int] = None, active=None):
+                           window: Optional[int] = None, active=None,
+                           impl: str = "auto"):
     """One-token decode over paged KV.  q: (B, 1, nq, h); arenas:
-    (P, BLOCK, nkv, h); page_table: (B, n_pg); pos: (B,).
+    (P, BLOCK, nkv * h); page_table: (B, n_pg); pos: (B,).
 
-    Gathers each row's pages into a dense (B, S, nkv, h) view and reuses
-    ``decode_attention``: logical slot j holds absolute position j, so the
-    position mask (<= pos, window) covers both the unwritten tail of the
-    last page and unallocated table entries.  On TPU with ``cfg.
-    use_kernels`` the gather happens inside the Pallas kernel via a
-    scalar-prefetched page table (kernels/decode_attention/paged)."""
+    The kernel streams each row's live pages out of the arena.  The jnp
+    path gathers each row's pages into a dense (B, S, nkv, h) view and
+    reuses ``decode_attention``: logical slot j holds absolute position j,
+    so the position mask (<= pos, window) covers both the unwritten tail
+    of the last page and unallocated table entries."""
+    if _use_kernel(impl):
+        lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+        return _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos,
+                             lengths, window, impl)
     B = q.shape[0]
-    blk = k_arena.shape[1]
-    S = page_table.shape[1] * blk
-    if cfg.use_kernels and jax.default_backend() == "tpu":
-        from repro.kernels.decode_attention import paged_decode_attention \
-            as paged_op
-        lengths = jnp.where(active, pos + 1, 0) if active is not None \
-            else pos + 1
-        o = paged_op(q[:, 0],                         # (B, H, h)
-                     k_arena, v_arena, page_table, lengths,
-                     window=window, softcap=cfg.attn_softcap or None,
-                     scale=_scale(cfg))
-        return o[:, None]                             # (B, 1, H, h)
-    kd = gather_pages(k_arena, page_table)
-    vd = gather_pages(v_arena, page_table)
+    S = page_table.shape[1] * k_arena.shape[1]
+    kd = gather_pages(k_arena, page_table, cfg.n_kv_heads)
+    vd = gather_pages(v_arena, page_table, cfg.n_kv_heads)
     kv_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     return decode_attention(cfg, q, kd, vd, kv_pos, pos,
                             window=window, active=active)
 
 
-def paged_prefill_attention(cfg, q, k_arena, v_arena, page_table,
-                            q_positions, window: Optional[int] = None,
-                            block_q: int = 512, active=None):
+def paged_prefill_attention(cfg, q, k_arena, v_arena, page_table, pos0,
+                            window: Optional[int] = None,
+                            block_q: int = 512, active=None,
+                            impl: str = "auto"):
     """Chunked-prefill attention over paged KV: the chunk's own K/V must
     already be scattered into the arena (update happens before attention,
-    matching the decode path).  q: (B, C, nq, h); q_positions: (B, C).
-    Causal masking over logical positions covers the not-yet-written tail
-    of the write page and unallocated table entries.
+    matching the decode path).  q: (B, C, nq, h), query c of row b at
+    position ``pos0[b] + c``.  Causal masking over logical positions
+    covers the not-yet-written tail of the write page and unallocated
+    table entries.
 
     ``active``: optional (B,) bool mask for batched-admission prefill —
     rows whose divergence suffix ended in an earlier chunk step ride
     along with zeroed output (their write already went to the scratch
     page), so a shared chunk grid never recompiles per occupancy."""
-    B = q.shape[0]
-    blk = k_arena.shape[1]
-    S = page_table.shape[1] * blk
-    kd = gather_pages(k_arena, page_table)
-    vd = gather_pages(v_arena, page_table)
+    B, C = q.shape[:2]
+    if _use_kernel(impl):
+        lengths = pos0 + C
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
+        return _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos0,
+                             lengths, window, impl)
+    S = page_table.shape[1] * k_arena.shape[1]
+    kd = gather_pages(k_arena, page_table, cfg.n_kv_heads)
+    vd = gather_pages(v_arena, page_table, cfg.n_kv_heads)
     kv_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    q_positions = pos0[:, None] + jnp.arange(C)[None]
     o = full_attention(cfg, q, kd, vd, q_positions, kv_pos,
                        causal=True, window=window, block_q=block_q)
     if active is not None:

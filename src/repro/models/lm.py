@@ -242,8 +242,8 @@ def layer_cache_zeros(cfg, spec, B, max_len, T_mem):
 # ==========================================================================
 #
 # The paged pool replaces each request's dense ``max_len`` KV strip with
-# block-granular pages in a node-wide (num_pages, BLOCK, nkv, h) arena per
-# layer (stacked over repeats: (R, num_pages, BLOCK, nkv, h)).  A request
+# block-granular pages in a node-wide (num_pages, BLOCK, nkv * h) arena per
+# layer (stacked over repeats: (R, num_pages, BLOCK, nkv * h)).  A request
 # is a page table — int32 physical page per logical block — so a prefix-
 # cache hit aliases the holder's pages (refcount bump, serving/page_pool)
 # instead of copying KV bytes, and pool memory scales with live tokens.
@@ -251,11 +251,13 @@ def layer_cache_zeros(cfg, spec, B, max_len, T_mem):
 # and have nothing to page; those families keep the dense slot pool.
 
 def layer_decode_paged(cfg, spec, p, x, pos, arena, page_table,
-                       active=None, write=True):
+                       active=None, write=True, impl="auto"):
     """Paged analogue of ``layer_decode`` for ``attn`` mixers.  arena:
-    {"k","v"}: (P, BLOCK, nkv, h); page_table: (B, n_pg); pos: (B,).
+    {"k","v"}: (P, BLOCK, nkv * h); page_table: (B, n_pg); pos: (B,).
     ``write=False`` skips the arena scatter (query-only replay over fully
-    cached tokens — never mutate pages another request may alias)."""
+    cached tokens — never mutate pages another request may alias).
+    ``impl`` picks the paged attention (``attention.paged_decode_
+    attention``)."""
     with jax.named_scope("qkv"):
         h = common.apply_norm(cfg, p["norm1"], x)
         q, k, v = attention.project_qkv(cfg, p["mixer"], h, pos[:, None],
@@ -268,7 +270,7 @@ def layer_decode_paged(cfg, spec, p, x, pos, arena, page_table,
     with jax.named_scope("attention"):
         o = attention.paged_decode_attention(cfg, q, ka, va, page_table,
                                              pos, window=spec.window,
-                                             active=active)
+                                             active=active, impl=impl)
     with jax.named_scope("out_proj"):
         h = attention.out_proj(cfg, p["mixer"], o)
         if cfg.double_norm:
@@ -280,7 +282,7 @@ def layer_decode_paged(cfg, spec, p, x, pos, arena, page_table,
 
 
 def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
-                        block_q=64, active=None):
+                        block_q=64, active=None, impl="auto"):
     """One teacher-forced prefill chunk: scatter the chunk's K/V into its
     (freshly allocated) write pages, then attend over all pages.  x:
     (B, C, d) with C == BLOCK and ``pos0`` (B,) block-aligned, so the
@@ -308,12 +310,13 @@ def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
         phys = page_table[jnp.arange(B), pos0 // blk]      # (B,)
         if active is not None:
             phys = jnp.where(active, phys, 0)          # dead rows -> scratch
-        ka = ka.at[phys].set(k)
-        va = va.at[phys].set(v)
+        ka = ka.at[phys].set(k.reshape(B, C, -1))
+        va = va.at[phys].set(v.reshape(B, C, -1))
     with jax.named_scope("attention"):
         o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                              positions, window=spec.window,
-                                              block_q=block_q, active=active)
+                                              pos0, window=spec.window,
+                                              block_q=block_q, active=active,
+                                              impl=impl)
     with jax.named_scope("out_proj"):
         h = attention.out_proj(cfg, p["mixer"], o)
         if cfg.double_norm:
@@ -324,7 +327,8 @@ def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
     return x, {"k": ka, "v": va}
 
 
-def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None):
+def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None,
+                       impl="auto"):
     """One speculation-window layer step: scatter the window's K/V by
     token position (may straddle a block boundary), then attend causally
     *inside* the window.  x: (B, W, d) with W == spec_k + 1 — row b's
@@ -353,8 +357,9 @@ def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None):
     active = None if n_tok is None else n_tok > 0
     with jax.named_scope("attention"):
         o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                              positions, window=spec.window,
-                                              block_q=64, active=active)
+                                              pos, window=spec.window,
+                                              block_q=64, active=active,
+                                              impl=impl)
     with jax.named_scope("out_proj"):
         h = attention.out_proj(cfg, p["mixer"], o)
         if cfg.double_norm:
@@ -367,7 +372,7 @@ def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None):
 
 def arena_gather_pages(arena, pages):
     """Gather physical pages out of a paged arena pytree: every
-    {"k","v"} leaf (R, num_pages, BLOCK, nkv, h) -> (R, n, BLOCK, nkv, h)
+    {"k","v"} leaf (R, num_pages, BLOCK, nkv * h) -> (R, n, BLOCK, nkv * h)
     for the n requested pages, in order.
 
     The overlay's cross-node page migration uses this to lift a prefix
@@ -380,7 +385,7 @@ def arena_gather_pages(arena, pages):
 
 
 def arena_scatter_pages(arena, pages, blocks):
-    """Inverse of ``arena_gather_pages``: write (R, n, BLOCK, nkv, h)
+    """Inverse of ``arena_gather_pages``: write (R, n, BLOCK, nkv * h)
     block payloads into freshly allocated physical pages of every arena
     leaf (cast to the arena dtype — wire payloads may arrive fp16/int8-
     dequantized).  The caller owns the target pages (refcount 1); aliased
@@ -615,18 +620,22 @@ class LM:
 
     def paged_arena_zeros(self, num_pages, block):
         """Node-wide paged KV arena: per pattern position {"k","v"} leaves
-        of shape (R, num_pages, BLOCK, nkv, d_head).  Page 0 is the
-        scratch page (serving/page_pool.NULL_PAGE)."""
+        of shape (R, num_pages, BLOCK, nkv * d_head), each page one
+        contiguous run that the paged kernel reads in place (a separate
+        minor d_head of 80 would pad to 128 lanes, and the TPU compiler
+        would lay the pages out along the minor dimension instead).  Page
+        0 is the scratch page (serving/page_pool.NULL_PAGE)."""
         cfg = self.cfg
         assert self.supports_paging(), cfg.name
-        shape = (cfg.n_repeats, num_pages, block, cfg.n_kv_heads, cfg.d_head)
+        shape = (cfg.n_repeats, num_pages, block,
+                 cfg.n_kv_heads * cfg.d_head)
         # one buffer per leaf: the engine donates the arena, and a buffer
         # may be donated only once per call
         return tuple({n: jnp.zeros(shape, cfg.compute_dtype)
                       for n in ("k", "v")} for _ in cfg.pattern)
 
     def prefill_paged(self, params, arena, page_tables, tokens, pos0,
-                      active=None):
+                      active=None, impl="auto"):
         """One teacher-forced chunk of prompt prefill over the paged pool.
 
         tokens: (B, C) with C == BLOCK; pos0: (B,) block-aligned chunk
@@ -641,7 +650,10 @@ class LM:
         of one request, each row with the request's whole page table), and
         rows the last dispatch of an admission leaves empty are masked
         (scratch-page writes, zeroed output) — K co-routed siblings cost
-        ceil(sum(chunks) / B) dispatches instead of sum(chunks)."""
+        ceil(sum(chunks) / B) dispatches instead of sum(chunks).
+
+        ``impl`` picks the paged attention of every layer (``attention.
+        paged_prefill_attention``): the kernel on a TPU by default."""
         cfg = self.cfg
         B, C = tokens.shape
         positions = pos0[:, None] + jnp.arange(C)[None]
@@ -654,7 +666,7 @@ class LM:
             for i, spec in enumerate(cfg.pattern):
                 x, a = layer_prefill_paged(cfg, spec, bp[i], x, pos0,
                                            ar[i], page_tables,
-                                           active=active)
+                                           active=active, impl=impl)
                 new.append(a)
             return constraints.constrain_batch(x), tuple(new)
 
@@ -664,11 +676,12 @@ class LM:
             return self._logits(params, x), arena
 
     def decode_paged(self, params, arena, page_tables, tokens, pos,
-                     active=None, write=True):
+                     active=None, write=True, impl="auto"):
         """Paged analogue of ``decode``: tokens (B, 1), pos (B,),
         page_tables (B, n_pg) physical page per logical block.  With
         ``write=False`` the arena is returned untouched (query-only replay
-        for full prefix hits — aliased pages are never mutated)."""
+        for full prefix hits — aliased pages are never mutated).  ``impl``
+        picks the paged attention, as in ``prefill_paged``."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, pos[:, None])
@@ -679,7 +692,7 @@ class LM:
             for i, spec in enumerate(cfg.pattern):
                 x, a = layer_decode_paged(cfg, spec, bp[i], x, pos, ar[i],
                                           page_tables, active=active,
-                                          write=write)
+                                          write=write, impl=impl)
                 new.append(a)
             return constraints.constrain_batch(x), tuple(new)
 
@@ -690,7 +703,7 @@ class LM:
         return logits, arena
 
     def verify_paged(self, params, arena, page_tables, tokens, pos,
-                     n_tok=None):
+                     n_tok=None, impl="auto"):
         """Speculative multi-token verify over the paged pool.
 
         tokens: (B, W) — per row, the committed next token followed by up
@@ -702,7 +715,8 @@ class LM:
         the window, so the returned (B, W, V) logits equal W sequential
         single-token decodes — the scheduler accepts the longest draft
         prefix matching greedy argmax and rolls back rejected tail KV by
-        simply not advancing the row position (append-only pages)."""
+        simply not advancing the row position (append-only pages).
+        ``impl`` picks the paged attention, as in ``prefill_paged``."""
         cfg = self.cfg
         B, W = tokens.shape
         positions = pos[:, None] + jnp.arange(W)[None]
@@ -714,7 +728,8 @@ class LM:
             new = []
             for i, spec in enumerate(cfg.pattern):
                 x, a = layer_verify_paged(cfg, spec, bp[i], x, pos, ar[i],
-                                          page_tables, n_tok=n_tok)
+                                          page_tables, n_tok=n_tok,
+                                          impl=impl)
                 new.append(a)
             return constraints.constrain_batch(x), tuple(new)
 

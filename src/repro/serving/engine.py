@@ -145,6 +145,10 @@ class RealEngine:
         self.prefill_tokens = 0           # real (non-pad) tokens prefilled
         self.prefill_slots = 0            # grid rows x BLOCK dispatched
         self.pages_evicted = 0            # pages freed by allocator pressure
+        # paged attention: live pages the attention visits per row, and
+        # rows x table width, summed over dispatches (count_attention)
+        self.attn_pages_read = 0
+        self.attn_pages_tabled = 0
         # spans and step records of this node (serving/trace.py)
         self.recorder = Recorder()
         # speculative decode counters (scheduler-driven verify rounds)
@@ -184,10 +188,14 @@ class RealEngine:
                 out_shardings=SingleDeviceSharding(self.device))(
                     self.num_pages, BLOCK)
             self.page_bytes = sum(
-                x.shape[0] * BLOCK * x.shape[3] * x.shape[4]
-                * x.dtype.itemsize for x in jax.tree.leaves(self.arena))
+                x.shape[0] * BLOCK * x.shape[3] * x.dtype.itemsize
+                for x in jax.tree.leaves(self.arena))
             self.prefix_cache.on_release = \
                 lambda h: self.allocator.decref(h.pages)
+            # the widest window of any layer: the pages a row's attention
+            # visits in at least one layer (None: the whole prefix)
+            wins = [sp.window for sp in cfg.pattern]
+            self.attn_window = None if None in wins else max(wins)
 
         def _prefill(params, tokens):
             return model.prefill(params, tokens, max_len=max_len,
@@ -313,6 +321,21 @@ class RealEngine:
         handle = PagedHandle(tuple(covered), n_cov * self.block)
         self.prefix_cache.insert(full_tokens, handle,
                                  n_cov * self.page_bytes)
+
+    def count_attention(self, pos0, lengths):
+        """Count one paged-attention dispatch from the positions the host
+        already holds: ``attn_pages_read`` gains the pages each row's
+        attention visits (its queries start at ``pos0``, its keys lie
+        below ``lengths``, 0 for a dead row; the window trims the front)
+        and ``attn_pages_tabled`` the rows x the table's width, which the
+        gather of the jnp path reads whatever the lengths."""
+        pos0, lengths = np.asarray(pos0), np.asarray(lengths)
+        last = np.minimum((lengths - 1) // self.block, self.max_pages - 1)
+        first = (0 if self.attn_window is None else
+                 np.maximum(pos0 - self.attn_window + 1, 0) // self.block)
+        self.attn_pages_read += int(
+            np.where(lengths > 0, last - first + 1, 0).sum())
+        self.attn_pages_tabled += lengths.size * self.max_pages
 
     def counters(self) -> dict:
         """The counters and gauges that the recorder's step records keep:
@@ -488,6 +511,7 @@ class RealEngine:
         """Block-aligned prompt fully cached: query-only replay of the
         last token — aliased pages are never written."""
         pt = jnp.asarray(self.page_table_row(pages))
+        self.count_attention([pos - 1], [pos])
         return self._query_paged(
             self.params, self.arena, pt,
             jnp.asarray([[toks[-1]]], jnp.int32),
@@ -512,6 +536,7 @@ class RealEngine:
             self.prefill_dispatches += 1
             self.prefill_tokens += len(chunk)
             self.prefill_slots += blk
+            self.count_attention([pos], [pos + blk])
             logits_last = logits[:, len(chunk) - 1]
             pos += len(chunk)
         return pos, logits_last
@@ -583,6 +608,7 @@ class RealEngine:
                         jnp.asarray(act))
                 self.prefill_dispatches += 1
                 self.prefill_slots += B * blk
+                self.count_attention(pos0, np.where(act, pos0 + blk, 0))
                 for i, off, r in ends:
                     r["logits"] = logits[i:i + 1, off - 1]
         except BaseException:
@@ -642,6 +668,7 @@ class RealEngine:
                 if nxt == req.eos_id or pos >= self.max_len - 1:
                     break
                 self.ensure_page_for(pages, pos)
+                self.count_attention([pos], [pos + 1])
                 logits, self.arena = self._decode_paged(
                     self.params, self.arena,
                     jnp.asarray(self.page_table_row(pages)),

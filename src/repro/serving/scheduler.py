@@ -261,6 +261,7 @@ class Scheduler:
             ins = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(act))
             if eng.paged:
                 ins = (jnp.asarray(self._ptab),) + ins
+                eng.count_attention(pos, np.where(act, pos + 1, 0))
         with self.rec.span("sched.decode_dispatch"):
             if eng.paged:
                 self._logits, eng.arena = eng._decode_batched(
@@ -326,6 +327,7 @@ class Scheduler:
             ntk[i] = n
             eng.ensure_page_for(s.pages, s.pos + n - 1)
             self._ptab[i, :len(s.pages)] = s.pages
+        eng.count_attention(pos, np.where(ntk > 0, pos + W, 0))
         logits, eng.arena = eng._verify_paged_batched(
             eng.params, eng.arena, jnp.asarray(self._ptab),
             jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(ntk))

@@ -10,7 +10,7 @@ the cluster would see.  tests/test_compression.py checks the quantization
 error bound and that error feedback keeps SGD convergent.
 
 ``compress_kv_blocks``/``decompress_kv_blocks`` serialize a gathered
-(R, n_pages, BLOCK, nkv, h) K/V slab for the ``kv_pages`` overlay message
+(R, n_pages, BLOCK, nkv * h) K/V slab for the ``kv_pages`` overlay message
 (serving/engine.export_pages -> import_pages): ``raw`` ships the arena
 dtype losslessly, ``fp16`` halves f32 wire bytes, ``int8`` quantizes with
 a per-(repeat, page) scale — the same max-abs scheme as the gradient path,
@@ -83,7 +83,7 @@ def _np_dtype(name: str) -> np.dtype:
 
 
 def compress_kv_blocks(blocks, mode: str = "fp16") -> dict:
-    """(R, n_pages, BLOCK, nkv, h) K/V slab -> msgpack-able wire record.
+    """(R, n_pages, BLOCK, nkv * h) K/V slab -> msgpack-able wire record.
 
     ``raw`` is lossless (arena dtype bytes as-is); ``fp16`` casts float32
     arenas down for half the wire bytes; ``int8`` quantizes with one
@@ -107,7 +107,7 @@ def compress_kv_blocks(blocks, mode: str = "fp16") -> dict:
 
 
 def decompress_kv_blocks(rec: dict, dtype=None):
-    """Wire record -> (R, n_pages, BLOCK, nkv, h) ndarray in ``dtype``
+    """Wire record -> (R, n_pages, BLOCK, nkv * h) ndarray in ``dtype``
     (defaults to the source arena dtype recorded at compression)."""
     shape = tuple(int(s) for s in rec["shape"])
     out_dtype = _np_dtype(str(dtype)) if dtype is not None \

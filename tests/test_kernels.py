@@ -8,7 +8,7 @@ import pytest
 from repro.kernels.chunk_hash.ops import chunk_hash_fixed
 from repro.kernels.chunk_hash.ref import chunk_hash_ref
 from repro.kernels.decode_attention.ops import (decode_attention,
-                                                paged_decode_attention)
+                                                paged_attention)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba_scan.ops import mamba_scan
 from repro.kernels.mlstm.ops import mlstm_chunkwise
@@ -58,32 +58,69 @@ def test_decode_attention(B, H, Hkv, S, D, window, softcap, ns):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("B,H,Hkv,D,blk,P,n_pg,window,softcap", [
-    (2, 4, 2, 64, 32, 12, 4, None, None),
-    (3, 4, 1, 64, 16, 9, 6, None, None),
-    (1, 8, 8, 128, 32, 6, 3, 48, None),
-    (2, 2, 2, 64, 32, 8, 4, None, 50.0),
-    (2, 32, 8, 80, 32, 9, 4, None, None),       # h2o-danube-1.8b heads
-    (2, 32, 8, 80, 32, 9, 4, 40, None),
-])
-def test_paged_decode_attention(B, H, Hkv, D, blk, P, n_pg, window,
-                                softcap):
-    """In-kernel page-table gather (scalar prefetch) vs the gather-then-
-    dense oracle, including a zero-length (masked slot-pool) row."""
-    ks = jax.random.split(jax.random.fold_in(RNG, P + n_pg + H), 4)
-    q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    ka = jax.random.normal(ks[1], (P, blk, Hkv, D), jnp.float32)
-    va = jax.random.normal(ks[2], (P, blk, Hkv, D), jnp.float32)
+# Paged attention cases.  ``rows``: one (pos0, length) per row, queries at
+# pos0 .. pos0 + C - 1 and keys below length (0 = dead row, zeros out).
+# ``share``: rows 0 and 1 alias the first ``share`` pages of row 0's table.
+_PAGED = {
+    # the decode kernel's cases, C = 1 (length n_pg * blk - 3 and dead rows)
+    "decode-g2": (1, 4, 2, 64, 32, 12, 4, None, None,
+                  [(124, 125), (0, 0)], 0),
+    "decode-g4-blk16": (1, 4, 1, 64, 16, 9, 6, None, None,
+                        [(92, 93), (0, 0), (0, 0)], 0),
+    "decode-d128-window": (1, 8, 8, 128, 32, 6, 3, 48, None,
+                           [(92, 93)], 0),
+    "decode-softcap": (1, 2, 2, 64, 32, 8, 4, None, 50.0,
+                       [(124, 125), (0, 0)], 0),
+    "decode-danube": (1, 32, 8, 80, 32, 9, 4, None, None,
+                      [(124, 125), (0, 0)], 0),
+    "decode-danube-window": (1, 32, 8, 80, 32, 9, 4, 40, None,
+                             [(124, 125), (0, 0)], 0),
+    # lengths ending mid-page and on a page boundary, an aliased prefix
+    "decode-ends-and-alias": (1, 8, 2, 16, 8, 20, 6, None, None,
+                              [(15, 16), (20, 21), (40, 41), (0, 0)], 2),
+    # prefill chunks, C = 32: a chunk at 0, one whose table names pages
+    # past its length, a dead grid row, group 8 at d_head 128
+    "prefill-g4": (32, 8, 2, 16, 32, 12, 5, None, None,
+                   [(0, 32), (64, 96), (0, 0)], 0),
+    "prefill-g8-d128": (32, 16, 2, 128, 32, 8, 3, None, None,
+                        [(32, 64), (0, 32)], 0),
+    "prefill-window-softcap": (32, 8, 2, 16, 32, 12, 5, 40, 30.0,
+                               [(96, 128), (32, 64)], 0),
+    "prefill-alias": (32, 4, 1, 16, 32, 12, 5, None, None,
+                      [(64, 96), (64, 96)], 2),
+    # a speculation window, C = W = 5: mid-page, across a page boundary,
+    # with a zero head (the model's head_pad rows)
+    "verify-w5": (5, 12, 2, 16, 8, 16, 6, None, None,
+                  [(3, 8), (6, 11), (0, 0)], 0),
+    "verify-w5-window": (5, 8, 2, 16, 8, 16, 6, 10, None,
+                         [(30, 35), (14, 19)], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAGED))
+def test_paged_attention(case):
+    """The kernel (interpret mode) against the gather-then-dense f32
+    oracle: in-kernel page reads through the scalar-prefetched table, the
+    per-query causal mask, the window, the softcap, dead rows."""
+    C, H, Hkv, D, blk, P, n_pg, window, softcap, rows, share = _PAGED[case]
+    B = len(rows)
+    ks = jax.random.split(jax.random.fold_in(RNG, P + n_pg + H + C), 4)
+    q = jax.random.normal(ks[0], (B, C, H, D), jnp.float32)
+    q = q.at[:, :, -2:].set(0.0) if case == "verify-w5" else q
+    ka = jax.random.normal(ks[1], (P, blk, Hkv * D), jnp.float32)
+    va = jax.random.normal(ks[2], (P, blk, Hkv * D), jnp.float32)
     pt = jax.random.randint(ks[3], (B, n_pg), 1, P)      # 0 = scratch page
-    lengths = jnp.asarray([n_pg * blk - 3] + [0] * (B - 1), jnp.int32)
-    ref = paged_decode_attention(q, ka, va, pt, lengths, window=window,
-                                 softcap=softcap, impl="ref")
-    pal = paged_decode_attention(q, ka, va, pt, lengths, window=window,
-                                 softcap=softcap, impl="interpret")
-    # rows with length 0 are fully masked garbage by contract (discarded
-    # by the slot-pool caller) — compare only the live row
-    np.testing.assert_allclose(np.asarray(pal[:1]), np.asarray(ref[:1]),
+    if share:
+        pt = pt.at[1, :share].set(pt[0, :share])
+    pos0 = jnp.asarray([r[0] for r in rows], jnp.int32)
+    lengths = jnp.asarray([r[1] for r in rows], jnp.int32)
+    kw = dict(window=window, softcap=softcap)
+    ref = paged_attention(q, ka, va, pt, pos0, lengths, impl="ref", **kw)
+    pal = paged_attention(q, ka, va, pt, pos0, lengths, impl="interpret",
+                          **kw)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+    assert not np.asarray(pal)[np.asarray(lengths) == 0].any()
 
 
 def test_paged_decode_matches_dense_decode():
@@ -98,11 +135,11 @@ def test_paged_decode_matches_dense_decode():
     lengths = jnp.asarray([S, S // 2 + 5], jnp.int32)
     dense = decode_attention(q, k, v, lengths, impl="ref")
     # lay request b's KV out as pages [b*n_pg .. b*n_pg+n_pg)
-    ka = k.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv, D)
-    va = v.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv, D)
+    ka = k.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv * D)
+    va = v.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv * D)
     pt = jnp.arange(B * n_pg, dtype=jnp.int32).reshape(B, n_pg)
-    paged = paged_decode_attention(q, ka, va, pt, lengths,
-                                   impl="interpret")
+    paged = paged_attention(q[:, None], ka, va, pt, lengths - 1, lengths,
+                            impl="interpret")[:, 0]
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                rtol=2e-4, atol=2e-4)
 

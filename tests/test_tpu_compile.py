@@ -2,10 +2,11 @@
 
 The TPU compiler refuses what interpret mode accepts: Pallas blocks that
 break the (8, 128) tiling rule, and programs that do not fit the chip's
-memory.  These tests compile the paged decode kernel at real head widths
-and the engine's full-width h2o-danube-1.8b steps (bf16 params beside the
-default KV arena) against a described v5e:2x2 topology.  Nothing runs; a
-pass says the chip's compiler accepts the programs and that each fits.
+memory.  These tests compile the paged attention kernel at real head
+widths and the engine's full-width h2o-danube-1.8b steps (bf16 params
+beside the default KV arena) against a described v5e:2x2 topology.
+Nothing runs; a pass says the chip's compiler accepts the programs, that
+each fits, and that the kernel reads the arena where it lies.
 
 The topology is described inside a fixture, never at import, so that
 every pytest-xdist worker collects the same tests.
@@ -13,6 +14,7 @@ every pytest-xdist worker collects the same tests.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import base
-from repro.kernels.decode_attention.paged import \
-    paged_decode_attention_pallas
+from repro.kernels.decode_attention.paged import paged_attention_pallas
 from repro.models.lm import build_model
 from repro.serving.prefix_cache import BLOCK
 
@@ -58,17 +59,42 @@ def _fits(compiled):
 
 @pytest.mark.parametrize("d_head", [80, 128])
 def test_paged_kernel_compiles(one_chip, d_head):
+    """The decode step's C = 1 and the prefill grid's C = 32, with 32
+    query heads over 8 KV heads and a window."""
     B, H, Hkv, P, n_pg = BATCH, 32, 8, NUM_PAGES, MAX_LEN // BLOCK
     bf16 = jnp.bfloat16
-    args = _on(one_chip, (
-        jax.ShapeDtypeStruct((B, H, d_head), bf16),
-        jax.ShapeDtypeStruct((P, BLOCK, Hkv, d_head), bf16),
-        jax.ShapeDtypeStruct((P, BLOCK, Hkv, d_head), bf16),
-        jax.ShapeDtypeStruct((B, n_pg), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.int32)))
-    fn = functools.partial(paged_decode_attention_pallas, window=MAX_LEN)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    for C in (1, BLOCK):
+        args = _on(one_chip, (
+            jax.ShapeDtypeStruct((B, C, H, d_head), bf16),
+            jax.ShapeDtypeStruct((P, BLOCK, Hkv * d_head), bf16),
+            jax.ShapeDtypeStruct((P, BLOCK, Hkv * d_head), bf16),
+            jax.ShapeDtypeStruct((B, n_pg), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32)))
+        fn = functools.partial(paged_attention_pallas, window=MAX_LEN)
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        _reads_arena_in_place(compiled, P)
+
+
+def _reads_arena_in_place(compiled, num_pages):
+    """The kernel reads the pages where they lie: no op of the program
+    transposes, gathers or re-lays out an array with the arena's pages
+    dimension (a copy that keeps the row-major layout is the layer scan's
+    own), and none gathers a whole page table's worth of positions."""
+    n_pg = MAX_LEN // BLOCK
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\{([\d,]*)[:}].*? "
+                      r"(copy|transpose|gather)\(", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        order = [int(d) for d in m.group(2).split(",") if d]
+        assert n_pg * BLOCK not in dims and BATCH * n_pg not in dims, \
+            line.strip()[:200]
+        if num_pages in dims:
+            assert m.group(3) == "copy", line.strip()[:200]
+            assert order == sorted(order, reverse=True), line.strip()[:200]
 
 
 @pytest.fixture(scope="module")
@@ -82,16 +108,12 @@ def danube(one_chip):
     return model, params, arena
 
 
-@pytest.mark.parametrize("use_kernels", [False, True],
-                         ids=["jnp", "kernel"])
-def test_danube_decode_step_fits(one_chip, danube, use_kernels,
-                                 monkeypatch):
+@pytest.mark.parametrize("impl", ["ref", "pallas"], ids=["jnp", "kernel"])
+def test_danube_decode_step_fits(one_chip, danube, impl):
+    """The decode step with the jnp gather path and with the kernel, which
+    a TPU picks by itself (this process's backend is the CPU, so the test
+    names the side)."""
     model, params, arena = danube
-    # the attention picks the kernel only on a TPU backend, and this
-    # process's backend is the CPU: steer it for this compile
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model = build_model(dataclasses.replace(model.cfg,
-                                            use_kernels=use_kernels))
     i32 = jnp.int32
     args = _on(one_chip, (
         jax.ShapeDtypeStruct((BATCH, MAX_LEN // BLOCK), i32),
@@ -100,15 +122,19 @@ def test_danube_decode_step_fits(one_chip, danube, use_kernels,
         jax.ShapeDtypeStruct((BATCH,), jnp.bool_)))
 
     def step(params, arena, pt, tok, pos, active):
-        return model.decode_paged(params, arena, pt, tok, pos, active=active)
+        return model.decode_paged(params, arena, pt, tok, pos, active=active,
+                                  impl=impl)
 
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, arena, *args).compile()
     _fits(compiled)
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernels
+    assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
+    if impl == "pallas":
+        _reads_arena_in_place(compiled, NUM_PAGES)
 
 
 def test_danube_prefill_step_fits(one_chip, danube):
+    """The prefill grid as a TPU serves it: through the kernel."""
     model, params, arena = danube
     i32 = jnp.int32
     args = _on(one_chip, (
@@ -119,8 +145,10 @@ def test_danube_prefill_step_fits(one_chip, danube):
 
     def step(params, arena, pt, tok, pos0, active):
         return model.prefill_paged(params, arena, pt, tok, pos0,
-                                   active=active)
+                                   active=active, impl="pallas")
 
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, arena, *args).compile()
     _fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    _reads_arena_in_place(compiled, NUM_PAGES)
