@@ -1,2 +1,1 @@
-from repro.kernels.decode_attention.ops import (  # noqa: F401
-    decode_attention, paged_attention)
+from repro.kernels.decode_attention.ops import decode_attention  # noqa: F401

@@ -23,24 +23,3 @@ def decode_attention(q, k, v, lengths, *, window=None, softcap=None,
                                    n_splits=n_splits,
                                    interpret=impl == "interpret")
 
-
-@functools.partial(jax.jit, static_argnames=("window", "softcap", "scale",
-                                             "impl"))
-def paged_attention(q, k_arena, v_arena, page_table, pos0, lengths, *,
-                    window=None, softcap=None, scale=None, impl="auto"):
-    """Paged attention of a C-query chunk per row: q (B, C, H, D) at
-    positions ``pos0[b] + c``; arenas (P, BLOCK, n_kv * D); page_table
-    (B, n_pg); lengths (B,).  The pallas path reads each row's live pages
-    in place through the scalar-prefetched page table."""
-    from repro.kernels.decode_attention.paged import paged_attention_pallas
-    from repro.kernels.decode_attention.ref import paged_attention_ref
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "ref":
-        return paged_attention_ref(q, k_arena, v_arena, page_table, pos0,
-                                   lengths, window=window, softcap=softcap,
-                                   scale=scale)
-    return paged_attention_pallas(q, k_arena, v_arena, page_table, pos0,
-                                  lengths, window=window, softcap=softcap,
-                                  scale=scale,
-                                  interpret=impl == "interpret")

@@ -10,7 +10,7 @@ D = 80 would pad to 128 lanes, and the compiler then makes the page index
 the minor dimension instead, which spreads every page over the whole slab.
 
 Grid: one step per row.  Inside a row a loop walks the blocks of
-``pages_per_block`` pages that hold a position some query of the row
+``block_pages`` pages that hold a position some query of the row
 attends (pages outside that range are neither fetched nor computed).  A
 block's K and V pages are copied from HBM by the page table, which rides
 in SMEM through scalar prefetch, into double-buffered VMEM while the
@@ -21,10 +21,10 @@ All KV heads share one step.  The wrapper spreads each query over a
 block-diagonal row of width ``n_kv * D`` (query head h's values sit in
 the columns of its KV head), so ``q_bd @ K_blockᵀ`` gives every query's
 scores against its own head only: the MXU does ``n_kv`` times the work,
-the VPU and the softmax none extra.  Each query row keeps an online
-softmax in f32 VMEM scratch; ``p @ V_block`` accumulates a ``(rows,
-n_kv * D)`` f32 sum whose own head's columns a 0/1 matmul picks out at
-the row's end.  Causal masking is per query position (the chunk's query
+the softmax statistics none extra.  The ``p @ V_block`` matmul and the
+f32 accumulator it feeds are ``n_kv`` times wider too: each query row
+keeps a ``(rows, n_kv * D)`` f32 sum, rescaled and rewritten every block,
+whose own head's columns a 0/1 matmul picks out at the row's end.  Causal masking is per query position (the chunk's query
 c sits at ``pos0 + c``), and the window is masked too.  A row of length 0
 (a dead slot-pool row or a masked grid row) writes zeros.
 """
@@ -74,24 +74,25 @@ def _kernel(pt_ref, pos_ref, len_ref,                    # SMEM (prefetch)
         first, last = _live_pages(pos_ref[row], len_ref[row], blk, window)
         return first, jnp.minimum(last, n_pg - 1)
 
-    def copies(row, j, slot):
-        """The 2 x npb page copies of block ``j`` of ``row`` into
-        ``slot``: pages outside the live range re-read its edge page,
+    def each_copy(row, j, slot, act):
+        """``act`` on the K and V copy of every live page of block ``j``
+        of ``row`` into ``slot``.  A slot whose page lies outside the live
+        range is neither fetched nor waited on: it keeps an older page,
         whose positions the mask hides."""
         first, last = live(row)
-        out = []
         for i in range(npb):
-            lp = jnp.clip(j * npb + i, first, last)
-            page = pt_ref[row * n_pg + lp]
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[slot, i], sem.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[slot, i], sem.at[1, slot]))
-        return out
+            lp = j * npb + i
+
+            @pl.when((lp >= first) & (lp <= last))
+            def _():
+                page = pt_ref[row * n_pg + lp]
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], kbuf.at[slot, i], sem.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[page], vbuf.at[slot, i], sem.at[1, slot]))
 
     def start(row, j, slot):
-        for c in copies(row, j, slot):
-            c.start()
+        each_copy(row, j, slot, lambda c: c.start())
 
     def next_live_row():
         return jax.lax.fori_loop(
@@ -103,6 +104,9 @@ def _kernel(pt_ref, pos_ref, len_ref,                    # SMEM (prefetch)
     @pl.when(b == 0)
     def _first_fetch():
         slot_ref[0] = 0
+        # a slot never fetched holds no page yet; the masked p is 0 there,
+        # and 0 x V must not meet a NaN left in fresh VMEM
+        vbuf[...] = jnp.zeros_like(vbuf)
         row = jax.lax.fori_loop(
             0, n_rows,
             lambda r, nxt: jnp.where((nxt == n_rows) & (len_ref[r] > 0),
@@ -144,8 +148,7 @@ def _kernel(pt_ref, pos_ref, len_ref,                    # SMEM (prefetch)
                 def _():
                     start(nxt, live(nxt)[0] // npb, 1 - slot)
 
-            for c in copies(b, j, slot):
-                c.wait()
+            each_copy(b, j, slot, lambda c: c.wait())
             k = kbuf[slot].reshape(npb * blk, -1)         # (T, n_kv*D)
             v = vbuf[slot].reshape(npb * blk, -1)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -185,7 +188,7 @@ def _kernel(pt_ref, pos_ref, len_ref,                    # SMEM (prefetch)
 
 def paged_attention_pallas(q, k_arena, v_arena, page_table, pos0, lengths,
                            *, window=None, softcap=None, scale=None,
-                           pages_per_block=None, interpret=False):
+                           interpret=False):
     """q: (B, C, H, D) queries at positions ``pos0[b] + c``; arenas:
     (P, BLOCK, n_kv * D) in HBM; page_table: (B, n_pg) physical page per
     logical block; pos0: (B,); lengths: (B,) keys ``[0, length)`` exist
@@ -200,7 +203,7 @@ def paged_attention_pallas(q, k_arena, v_arena, page_table, pos0, lengths,
     n_pg = page_table.shape[1]
     rows = H * C
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    npb = pages_per_block or block_pages(rows, n_pg, blk)
+    npb = block_pages(rows, n_pg, blk)
 
     # rows ordered (query head, chunk offset); row r's KV head is
     # r // (group * C), its offset r % C
@@ -248,7 +251,10 @@ def paged_attention_pallas(q, k_arena, v_arena, page_table, pos0, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 2 ** 20),
-        interpret=interpret,
+        # the TPU interpreter runs each copy at its wait and fills fresh
+        # VMEM with NaN, so a copy waited on wrongly or a slot read before
+        # it is filled shows in the result
+        interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_attention",     # the kernel's name in a device trace
     )(page_table.astype(jnp.int32).reshape(-1), pos0.astype(jnp.int32),
       n_len, q_bd, jnp.asarray(q_off[:, None]), jnp.asarray(q_kv[:, None]),

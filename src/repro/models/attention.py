@@ -215,7 +215,7 @@ def update_cache(k_cache, v_cache, kv_positions, k_new, v_new, slot):
 #
 # ``impl`` picks the attention: "auto" is the Pallas kernel
 # (kernels/decode_attention/paged.py) on a TPU and the jnp gather path
-# elsewhere; "pallas", "interpret" (the kernel in Pallas's interpreter)
+# elsewhere; "pallas", "interpret" (the kernel in Pallas's TPU interpreter)
 # and "ref" (the jnp gather path) force a side.
 
 def _use_kernel(impl: str) -> bool:

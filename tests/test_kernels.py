@@ -7,8 +7,9 @@ import pytest
 
 from repro.kernels.chunk_hash.ops import chunk_hash_fixed
 from repro.kernels.chunk_hash.ref import chunk_hash_ref
-from repro.kernels.decode_attention.ops import (decode_attention,
-                                                paged_attention)
+from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.decode_attention.paged import paged_attention_pallas
+from repro.kernels.decode_attention.ref import paged_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba_scan.ops import mamba_scan
 from repro.kernels.mlstm.ops import mlstm_chunkwise
@@ -99,8 +100,9 @@ _PAGED = {
 
 @pytest.mark.parametrize("case", list(_PAGED))
 def test_paged_attention(case):
-    """The kernel (interpret mode) against the gather-then-dense f32
-    oracle: in-kernel page reads through the scalar-prefetched table, the
+    """The kernel (TPU interpret mode: each page copy runs at its wait,
+    fresh VMEM holds NaN) against the gather-then-dense f32 oracle:
+    in-kernel page reads through the scalar-prefetched table, the
     per-query causal mask, the window, the softcap, dead rows."""
     C, H, Hkv, D, blk, P, n_pg, window, softcap, rows, share = _PAGED[case]
     B = len(rows)
@@ -115,9 +117,9 @@ def test_paged_attention(case):
     pos0 = jnp.asarray([r[0] for r in rows], jnp.int32)
     lengths = jnp.asarray([r[1] for r in rows], jnp.int32)
     kw = dict(window=window, softcap=softcap)
-    ref = paged_attention(q, ka, va, pt, pos0, lengths, impl="ref", **kw)
-    pal = paged_attention(q, ka, va, pt, pos0, lengths, impl="interpret",
-                          **kw)
+    ref = paged_attention_ref(q, ka, va, pt, pos0, lengths, **kw)
+    pal = paged_attention_pallas(q, ka, va, pt, pos0, lengths,
+                                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
     assert not np.asarray(pal)[np.asarray(lengths) == 0].any()
@@ -138,8 +140,8 @@ def test_paged_decode_matches_dense_decode():
     ka = k.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv * D)
     va = v.transpose(0, 2, 1, 3).reshape(B * n_pg, blk, Hkv * D)
     pt = jnp.arange(B * n_pg, dtype=jnp.int32).reshape(B, n_pg)
-    paged = paged_attention(q[:, None], ka, va, pt, lengths - 1, lengths,
-                            impl="interpret")[:, 0]
+    paged = paged_attention_pallas(q[:, None], ka, va, pt, lengths - 1,
+                                   lengths, interpret=True)[:, 0]
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                rtol=2e-4, atol=2e-4)
 
