@@ -24,9 +24,10 @@ scores against its own head only: the MXU does ``n_kv`` times the work,
 the softmax statistics none extra.  The ``p @ V_block`` matmul and the
 f32 accumulator it feeds are ``n_kv`` times wider too: each query row
 keeps a ``(rows, n_kv * D)`` f32 sum, rescaled and rewritten every block,
-whose own head's columns a 0/1 matmul picks out at the row's end.  Causal masking is per query position (the chunk's query
-c sits at ``pos0 + c``), and the window is masked too.  A row of length 0
-(a dead slot-pool row or a masked grid row) writes zeros.
+whose own head's columns a 0/1 matmul picks out at the row's end.  Causal
+masking is per query position (the chunk's query c sits at ``pos0 + c``),
+and the window is masked too.  A row of length 0 (a dead slot-pool row or
+a masked grid row) writes zeros.
 """
 from __future__ import annotations
 
