@@ -63,19 +63,26 @@ def test_decode_attention(B, H, Hkv, S, D, window, softcap, ns):
 # pos0 .. pos0 + C - 1 and keys below length (0 = dead row, zeros out).
 # ``share``: rows 0 and 1 alias the first ``share`` pages of row 0's table.
 _PAGED = {
-    # the decode kernel's cases, C = 1 (length n_pg * blk - 3 and dead rows)
-    "decode-g2": (1, 4, 2, 64, 32, 12, 4, None, None,
-                  [(124, 125), (0, 0)], 0),
-    "decode-g4-blk16": (1, 4, 1, 64, 16, 9, 6, None, None,
-                        [(92, 93), (0, 0), (0, 0)], 0),
-    "decode-d128-window": (1, 8, 8, 128, 32, 6, 3, 48, None,
-                           [(92, 93)], 0),
-    "decode-softcap": (1, 2, 2, 64, 32, 8, 4, None, 50.0,
-                       [(124, 125), (0, 0)], 0),
-    "decode-danube": (1, 32, 8, 80, 32, 9, 4, None, None,
-                      [(124, 125), (0, 0)], 0),
-    "decode-danube-window": (1, 32, 8, 80, 32, 9, 4, 40, None,
-                             [(124, 125), (0, 0)], 0),
+    # the decode kernel's cases, C = 1 (length n_pg * blk - 3 and dead rows),
+    # named B-H-Hkv-D-blk-P-n_pg-window-softcap as they always were
+    "2-4-2-64-32-12-4-None-None": (
+        1, 4, 2, 64, 32, 12, 4, None, None,
+        [(124, 125), (0, 0)], 0),
+    "3-4-1-64-16-9-6-None-None": (
+        1, 4, 1, 64, 16, 9, 6, None, None,
+        [(92, 93), (0, 0), (0, 0)], 0),
+    "1-8-8-128-32-6-3-48-None": (
+        1, 8, 8, 128, 32, 6, 3, 48, None,
+        [(92, 93)], 0),
+    "2-2-2-64-32-8-4-None-50.0": (
+        1, 2, 2, 64, 32, 8, 4, None, 50.0,
+        [(124, 125), (0, 0)], 0),
+    "2-32-8-80-32-9-4-None-None": (
+        1, 32, 8, 80, 32, 9, 4, None, None,
+        [(124, 125), (0, 0)], 0),
+    "2-32-8-80-32-9-4-40-None": (
+        1, 32, 8, 80, 32, 9, 4, 40, None,
+        [(124, 125), (0, 0)], 0),
     # lengths ending mid-page and on a page boundary, an aliased prefix
     "decode-ends-and-alias": (1, 8, 2, 16, 8, 20, 6, None, None,
                               [(15, 16), (20, 21), (40, 41), (0, 0)], 2),
@@ -99,7 +106,7 @@ _PAGED = {
 
 
 @pytest.mark.parametrize("case", list(_PAGED))
-def test_paged_attention(case):
+def test_paged_decode_attention(case):
     """The kernel (TPU interpret mode: each page copy runs at its wait,
     fresh VMEM holds NaN) against the gather-then-dense f32 oracle:
     in-kernel page reads through the scalar-prefetched table, the
