@@ -4,7 +4,7 @@ Same requests through the same paged scheduler twice: baseline (one
 token per round, PR 1-3 path) and speculative (`cfg.spec_enabled`: a
 host-side prompt-lookup drafter proposes up to `spec_k` tokens per slot
 and every round verifies the whole pool's drafts in ONE multi-token
-`verify_paged` dispatch).  Outputs are token-identical by construction
+verify dispatch, `RealEngine._verify_paged_batched`).  Outputs are token-identical by construction
 (drafts are only accepted when they equal the model's own greedy
 argmax); what changes is dispatches per generated token.
 

@@ -215,8 +215,8 @@ def kernel_check(engine, prompt_list, offsets=(1, 40, 97, 190)):
     outs, compiled = {}, {}
     for impl in ("pallas", "ref"):
         def query(params, arena, pt, tok, pos, impl=impl):
-            return model.decode_paged(params, arena, pt, tok, pos,
-                                      write=False, impl=impl)[0][:, :vocab]
+            return model.step_paged(params, arena, pt, tok, pos, write=None,
+                                    impl=impl)[0][:, -1, :vocab]
 
         args = (engine.params, engine.arena, pt, tok, pos)
         compiled[impl] = jax.jit(query).lower(*args).compile()
@@ -245,20 +245,20 @@ def prefill_kernel_check(engine, prompt_list, rows=4, starts=(0, 64, 192)):
     pt = np.zeros((rows, engine.max_pages), np.int32)
     tok = np.zeros((rows, blk), np.int32)
     pos0 = np.zeros((rows,), np.int32)
-    act = np.zeros((rows,), bool)
+    n_tok = np.zeros((rows,), np.int32)
     for r, (toks, p0) in enumerate(grid):
         _, pages = _cached(engine, toks, p0 + blk)
         pt[r, :len(pages)] = pages
-        tok[r], pos0[r], act[r] = toks[p0:p0 + blk], p0, True
+        tok[r], pos0[r], n_tok[r] = toks[p0:p0 + blk], p0, blk
     outs = {}
     for impl in ("pallas", "ref"):
-        def prefill(params, arena, pt, tok, pos0, act, impl=impl):
-            return model.prefill_paged(params, arena, pt, tok, pos0,
-                                       active=act, impl=impl)[0]
+        def prefill(params, arena, pt, tok, pos0, n_tok, impl=impl):
+            return model.step_paged(params, arena, pt, tok, pos0, n_tok=n_tok,
+                                    write="page", impl=impl)[0]
 
         outs[impl] = jax.jit(prefill)(engine.params, engine.arena, pt, tok,
-                                      pos0, act)[:, :, :vocab]
-    live = np.flatnonzero(act)
+                                      pos0, n_tok)[:, :, :vocab]
+    live = np.flatnonzero(n_tok)
     compare_logits("kernel prefill vs jnp prefill",
                    np.asarray(outs["pallas"])[live],
                    np.asarray(outs["ref"])[live])

@@ -118,7 +118,7 @@ class ArchConfig:
     # speculative decode (serving-time policy, not an architecture trait:
     # no effect on params/init).  With ``spec_enabled`` a paged scheduler
     # verifies up to ``spec_k`` self-drafted n-gram tokens per slot per
-    # round in one multi-token dispatch (models/lm.verify_paged); outputs
+    # round in one multi-token dispatch (models/lm.LM.step_paged); outputs
     # stay token-identical to greedy non-speculative decoding.
     spec_enabled: bool = False
     spec_k: int = 4

@@ -3,9 +3,10 @@
 The full-sequence path is *chunked over query blocks* (lax.scan) so the jnp
 reference path lowered in the dry-run never materializes an (S, S) score
 tensor — same O(S^2) FLOPs as flash attention with O(S * block_q) memory.
-The paged serving paths (the decode step and the prefill grid) run the
-Pallas paged kernel (kernels/decode_attention/paged.py) on a TPU and a jnp
-gather path elsewhere; the full-sequence and dense decode paths are jnp.
+The paged serving attention (``paged_attention``: the decode step, the
+prefill grid and the verify window) runs the Pallas paged kernel
+(kernels/decode_attention/paged.py) on a TPU and a jnp gather path
+elsewhere; the full-sequence and dense decode paths are jnp.
 """
 from __future__ import annotations
 
@@ -218,21 +219,6 @@ def update_cache(k_cache, v_cache, kv_positions, k_new, v_new, slot):
 # elsewhere; "pallas", "interpret" (the kernel in Pallas's TPU interpreter)
 # and "ref" (the jnp gather path) force a side.
 
-def _use_kernel(impl: str) -> bool:
-    if impl == "auto":
-        return jax.default_backend() == "tpu"
-    return impl != "ref"
-
-
-def _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos0, lengths,
-                  window, impl):
-    from repro.kernels.decode_attention.paged import paged_attention_pallas
-    return paged_attention_pallas(
-        q, k_arena, v_arena, page_table, pos0, lengths, window=window,
-        softcap=cfg.attn_softcap or None, scale=_scale(cfg),
-        interpret=impl == "interpret")
-
-
 def gather_pages(arena, page_table, n_kv):
     """arena: (P, BLOCK, nkv * h); page_table: (B, n_pg) int32 physical
     page per logical block -> (B, n_pg * BLOCK, nkv, h) dense per-request
@@ -247,34 +233,30 @@ def gather_pages(arena, page_table, n_kv):
     return g.reshape(B, n_pg * blk, n_kv, width // n_kv)
 
 
-def update_paged_cache(k_arena, v_arena, k_new, v_new, page_table, pos):
-    """Scatter (B, 1, nkv, h) new K/V into the arena at each row's write
-    page: physical page ``page_table[b, pos[b] // BLOCK]``, offset
-    ``pos[b] % BLOCK``.  Rows whose table points at the scratch page
-    (inactive slots) write there harmlessly."""
-    B = page_table.shape[0]
+def write_page(k_arena, v_arena, k_new, v_new, page_table, pos, n_tok):
+    """Write a (B, BLOCK, nkv, h) chunk that starts on a page boundary:
+    row b's chunk fills the whole physical page ``page_table[b, pos[b] //
+    BLOCK]``.  Dead rows (``n_tok`` 0) write the scratch page 0, so an
+    empty grid row never corrupts a live page."""
+    B, C = k_new.shape[:2]
     blk = k_arena.shape[1]
-    bidx = jnp.arange(B)
-    phys = page_table[bidx, pos // blk]               # (B,)
-    off = pos % blk
-    k_arena = k_arena.at[phys, off].set(k_new[:, 0].reshape(B, -1))
-    v_arena = v_arena.at[phys, off].set(v_new[:, 0].reshape(B, -1))
+    phys = page_table[jnp.arange(B), pos // blk]          # (B,)
+    if n_tok is not None:
+        phys = jnp.where(n_tok > 0, phys, 0)
+    k_arena = k_arena.at[phys].set(k_new.reshape(B, C, -1))
+    v_arena = v_arena.at[phys].set(v_new.reshape(B, C, -1))
     return k_arena, v_arena
 
 
-def update_paged_cache_window(k_arena, v_arena, k_new, v_new, page_table,
-                              pos, n_tok=None):
-    """Scatter a (B, W, nkv, h) *speculation window* of new K/V into the
-    arena: token m of row b lands at physical page ``page_table[b,
-    (pos[b]+m) // BLOCK]``, offset ``(pos[b]+m) % BLOCK`` — the window may
-    straddle a block boundary, unlike the one-token ``update_paged_cache``
-    or the page-aligned prefill scatter.
+def write_tokens(k_arena, v_arena, k_new, v_new, page_table, pos, n_tok):
+    """Write a (B, W, nkv, h) window token by token: token m of row b
+    lands at physical page ``page_table[b, (pos[b]+m) // BLOCK]``, offset
+    ``(pos[b]+m) % BLOCK``, so the window may straddle a page boundary.
 
-    ``n_tok``: optional (B,) int32 count of real tokens per row (draft
-    windows are ragged; dead slot-pool rows carry 0).  Positions at or
-    beyond ``n_tok`` scatter onto the scratch page 0 so pad/dead tokens
-    never touch a live page, and their table lookup is clamped so a row
-    parked near ``max_len`` cannot index past its page table."""
+    Tokens at or beyond ``n_tok[b]`` (ragged drafts; 0 for a dead row)
+    write the scratch page 0, so pad and dead tokens never touch a live
+    page, and the table lookup is clamped so a row parked near
+    ``max_len`` cannot index past its page table."""
     B, W = k_new.shape[:2]
     blk = k_arena.shape[1]
     n_pg = page_table.shape[1]
@@ -289,61 +271,44 @@ def update_paged_cache_window(k_arena, v_arena, k_new, v_new, page_table,
     return k_arena, v_arena
 
 
-def paged_decode_attention(cfg, q, k_arena, v_arena, page_table, pos,
-                           window: Optional[int] = None, active=None,
-                           impl: str = "auto"):
-    """One-token decode over paged KV.  q: (B, 1, nq, h); arenas:
-    (P, BLOCK, nkv * h); page_table: (B, n_pg); pos: (B,).
+KV_WRITES = {"page": write_page, "tokens": write_tokens}
 
-    The kernel streams each row's live pages out of the arena.  The jnp
-    path gathers each row's pages into a dense (B, S, nkv, h) view and
-    reuses ``decode_attention``: logical slot j holds absolute position j,
-    so the position mask (<= pos, window) covers both the unwritten tail
-    of the last page and unallocated table entries."""
-    if _use_kernel(impl):
-        lengths = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
-        return _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos,
-                             lengths, window, impl)
-    B = q.shape[0]
+
+def paged_attention(cfg, q, k_arena, v_arena, page_table, pos0, n_tok,
+                    window: Optional[int] = None, impl: str = "auto"):
+    """Attention of a (B, W, nq, h) query window over paged KV whose own
+    K/V is already in the arena.  Query m of row b sits at position
+    ``pos0[b] + m`` and attends causally (and within ``window``) over its
+    row's pages; ``n_tok`` (B,) counts each row's real tokens, 0 for a
+    dead row, whose output is zero (None: every row is live).
+
+    The kernel streams each row's live pages, below ``pos0 + W``, out of
+    the arena.  The jnp path gathers each row's pages into a dense view:
+    logical slot j holds position j, so the causal mask covers the
+    unwritten tail of the last page and unallocated table entries."""
+    B, W = q.shape[:2]
+    live = None if n_tok is None else n_tok > 0
+    use_kernel = (jax.default_backend() == "tpu" if impl == "auto"
+                  else impl != "ref")
+    if use_kernel:
+        from repro.kernels.decode_attention.paged import \
+            paged_attention_pallas
+        lengths = pos0 + W
+        if live is not None:
+            lengths = jnp.where(live, lengths, 0)
+        return paged_attention_pallas(
+            q, k_arena, v_arena, page_table, pos0, lengths, window=window,
+            softcap=cfg.attn_softcap or None, scale=_scale(cfg),
+            interpret=impl == "interpret")
     S = page_table.shape[1] * k_arena.shape[1]
     kd = gather_pages(k_arena, page_table, cfg.n_kv_heads)
     vd = gather_pages(v_arena, page_table, cfg.n_kv_heads)
     kv_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    return decode_attention(cfg, q, kd, vd, kv_pos, pos,
-                            window=window, active=active)
-
-
-def paged_prefill_attention(cfg, q, k_arena, v_arena, page_table, pos0,
-                            window: Optional[int] = None,
-                            block_q: int = 512, active=None,
-                            impl: str = "auto"):
-    """Chunked-prefill attention over paged KV: the chunk's own K/V must
-    already be scattered into the arena (update happens before attention,
-    matching the decode path).  q: (B, C, nq, h), query c of row b at
-    position ``pos0[b] + c``.  Causal masking over logical positions
-    covers the not-yet-written tail of the write page and unallocated
-    table entries.
-
-    ``active``: optional (B,) bool mask for batched-admission prefill —
-    rows whose divergence suffix ended in an earlier chunk step ride
-    along with zeroed output (their write already went to the scratch
-    page), so a shared chunk grid never recompiles per occupancy."""
-    B, C = q.shape[:2]
-    if _use_kernel(impl):
-        lengths = pos0 + C
-        if active is not None:
-            lengths = jnp.where(active, lengths, 0)
-        return _paged_kernel(cfg, q, k_arena, v_arena, page_table, pos0,
-                             lengths, window, impl)
-    S = page_table.shape[1] * k_arena.shape[1]
-    kd = gather_pages(k_arena, page_table, cfg.n_kv_heads)
-    vd = gather_pages(v_arena, page_table, cfg.n_kv_heads)
-    kv_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    q_positions = pos0[:, None] + jnp.arange(C)[None]
+    q_positions = pos0[:, None] + jnp.arange(W)[None]
     o = full_attention(cfg, q, kd, vd, q_positions, kv_pos,
-                       causal=True, window=window, block_q=block_q)
-    if active is not None:
-        o = jnp.where(active[:, None, None, None], o, 0.0)
+                       causal=True, window=window, block_q=W)
+    if live is not None:
+        o = jnp.where(live[:, None, None, None], o, 0.0)
     return o
 
 

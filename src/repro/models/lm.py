@@ -2,18 +2,24 @@
 
 Params are nested dicts; the repeating block ``cfg.pattern`` is stacked over
 ``cfg.n_repeats`` and executed with ``lax.scan`` (compact HLO for the
-512-device dry-run).  Three entry points per model:
+512-device dry-run).  Four entry points per model:
 
   apply(params, tokens, aux)            full causal logits      (train)
-  prefill(params, tokens, aux, max_len) last logits + cache     (serving)
-  decode(params, cache, tokens, pos)    next logits + cache     (serving)
+  prefill(params, tokens, aux, max_len) last logits + cache     (dense pool)
+  decode(params, cache, tokens, pos)    next logits + cache     (dense pool)
+  step_paged(params, arena, page_tables, tokens, pos, n_tok, write)
+                                        window logits + arena   (paged pool)
 
-Caches are pytrees stacked over repeats (tuple over pattern positions):
+Dense caches are pytrees stacked over repeats (tuple over pattern
+positions):
   attn      {"k","v"}: (R, B, size, n_kv, d_head); size = window or max_len
   cross     {"k","v"}: (R, B, T_mem, n_kv, d_head)  (static, no update)
   mamba     {"conv","h"}
   mlstm     {"C","n","m","conv"}
   slstm     {"h","c","n","m"}
+The paged arena (pure-attention patterns) holds per pattern position
+{"k","v"}: (R, num_pages, BLOCK, n_kv * d_head), one node-wide pool of pages
+that requests index through page tables (``paged_arena_zeros``).
 """
 from __future__ import annotations
 
@@ -250,116 +256,43 @@ def layer_cache_zeros(cfg, spec, B, max_len, T_mem):
 # Recurrent mixers (mamba/xLSTM) summarize the whole stream in O(1) state
 # and have nothing to page; those families keep the dense slot pool.
 
-def layer_decode_paged(cfg, spec, p, x, pos, arena, page_table,
-                       active=None, write=True, impl="auto"):
-    """Paged analogue of ``layer_decode`` for ``attn`` mixers.  arena:
-    {"k","v"}: (P, BLOCK, nkv * h); page_table: (B, n_pg); pos: (B,).
-    ``write=False`` skips the arena scatter (query-only replay over fully
-    cached tokens — never mutate pages another request may alias).
-    ``impl`` picks the paged attention (``attention.paged_decode_
-    attention``)."""
-    with jax.named_scope("qkv"):
-        h = common.apply_norm(cfg, p["norm1"], x)
-        q, k, v = attention.project_qkv(cfg, p["mixer"], h, pos[:, None],
-                                        rope=True)
-    ka, va = arena["k"], arena["v"]
-    if write:
-        with jax.named_scope("kv_write"):
-            ka, va = attention.update_paged_cache(ka, va, k, v, page_table,
-                                                  pos)
-    with jax.named_scope("attention"):
-        o = attention.paged_decode_attention(cfg, q, ka, va, page_table,
-                                             pos, window=spec.window,
-                                             active=active, impl=impl)
-    with jax.named_scope("out_proj"):
-        h = attention.out_proj(cfg, p["mixer"], o)
-        if cfg.double_norm:
-            h = common.apply_norm(cfg, p["norm1b"], h)
-        x = x + h
-    with jax.named_scope("mlp"):
-        x, _ = _ffn_block(cfg, spec, p, x)
-    return x, {"k": ka, "v": va}
+def layer_paged(cfg, spec, p, x, pos, arena, page_table, n_tok, write,
+                impl):
+    """One ``attn`` layer over the paged pool.  x: (B, W, d), query m of
+    row b at position ``pos[b] + m``; arena: {"k","v"}: (P, BLOCK,
+    nkv * h); page_table: (B, n_pg); ``n_tok`` (B,) counts each row's
+    real tokens, 0 for a dead row (None: all W tokens of every row).
 
+    ``write`` names the K/V write (``attention.KV_WRITES``): "page" for a
+    BLOCK-token chunk that starts on a page boundary (the prefill grid),
+    "tokens" for any window written token by token (the decode step at
+    W = 1, the verify window), None for the query-only replay of a fully
+    cached prompt, which never writes a page another request may alias.
+    ``impl`` picks the paged attention (``attention.paged_attention``).
 
-def layer_prefill_paged(cfg, spec, p, x, pos0, arena, page_table,
-                        block_q=64, active=None, impl="auto"):
-    """One teacher-forced prefill chunk: scatter the chunk's K/V into its
-    (freshly allocated) write pages, then attend over all pages.  x:
-    (B, C, d) with C == BLOCK and ``pos0`` (B,) block-aligned, so the
-    chunk covers exactly logical block ``pos0 // BLOCK`` of every row.
-
-    ``active``: optional (B,) bool mask (batched admission over a shared
-    chunk grid) — inactive rows scatter onto the scratch page 0 instead
-    of a live page, so empty rows never corrupt the arena.
-
-    Invariant batched admission rests on: ALL rows scatter their K/V
-    into the arena before ANY row attends, in every layer.  Rows holding
+    Invariant batched admission rests on: ALL rows write their K/V into
+    the arena before ANY row attends, in every layer.  Rows holding
     consecutive chunks of one request (with the same page table) thus
     read their earlier siblings' K/V of this layer, and the causal and
-    window masks hide the later ones — one dispatch computes exactly
-    what the chunks would compute one after another."""
-    B, C, _ = x.shape
+    window masks hide the later ones — one dispatch computes exactly what
+    the chunks would compute one after another.  For a verify window,
+    token m attends exactly the KV a sequential decode at ``pos + m``
+    would see, and a rejected tail needs no rollback: pages are
+    append-only per row and the position mask hides what lies beyond the
+    committed position until it is overwritten."""
+    positions = pos[:, None] + jnp.arange(x.shape[1])[None]
+    with jax.named_scope("qkv"):
+        h = common.apply_norm(cfg, p["norm1"], x)
+        q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
+                                        rope=True)
     ka, va = arena["k"], arena["v"]
-    blk = ka.shape[1]
-    positions = pos0[:, None] + jnp.arange(C)[None]
-    with jax.named_scope("qkv"):
-        h = common.apply_norm(cfg, p["norm1"], x)
-        q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
-                                        rope=True)
-    with jax.named_scope("kv_write"):
-        phys = page_table[jnp.arange(B), pos0 // blk]      # (B,)
-        if active is not None:
-            phys = jnp.where(active, phys, 0)          # dead rows -> scratch
-        ka = ka.at[phys].set(k.reshape(B, C, -1))
-        va = va.at[phys].set(v.reshape(B, C, -1))
+    if write is not None:
+        with jax.named_scope("kv_write"):
+            ka, va = attention.KV_WRITES[write](ka, va, k, v, page_table,
+                                                pos, n_tok)
     with jax.named_scope("attention"):
-        o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                              pos0, window=spec.window,
-                                              block_q=block_q, active=active,
-                                              impl=impl)
-    with jax.named_scope("out_proj"):
-        h = attention.out_proj(cfg, p["mixer"], o)
-        if cfg.double_norm:
-            h = common.apply_norm(cfg, p["norm1b"], h)
-        x = x + h
-    with jax.named_scope("mlp"):
-        x, _ = _ffn_block(cfg, spec, p, x)
-    return x, {"k": ka, "v": va}
-
-
-def layer_verify_paged(cfg, spec, p, x, pos, arena, page_table, n_tok=None,
-                       impl="auto"):
-    """One speculation-window layer step: scatter the window's K/V by
-    token position (may straddle a block boundary), then attend causally
-    *inside* the window.  x: (B, W, d) with W == spec_k + 1 — row b's
-    window is [committed token, draft_1 .. draft_k] starting at absolute
-    position ``pos[b]``; ``n_tok`` (B,) counts the real tokens per row
-    (ragged drafts; 0 = dead slot) — pad/dead tokens scatter onto the
-    scratch page and their outputs are zeroed.
-
-    Teacher-forced verification: token m's hidden state attends exactly
-    the KV a sequential decode at position pos+m would see (committed
-    pages plus the window's own earlier tokens, just scattered), so the
-    returned logits are the sequential greedy logits for every window
-    position — acceptance is decided on the host by comparing drafts
-    against argmax, and rejected tail KV needs no device rollback: pages
-    are append-only per row and the position mask hides anything beyond
-    the committed position until it is overwritten."""
-    B, W, _ = x.shape
-    positions = pos[:, None] + jnp.arange(W)[None]
-    with jax.named_scope("qkv"):
-        h = common.apply_norm(cfg, p["norm1"], x)
-        q, k, v = attention.project_qkv(cfg, p["mixer"], h, positions,
-                                        rope=True)
-    with jax.named_scope("kv_write"):
-        ka, va = attention.update_paged_cache_window(
-            arena["k"], arena["v"], k, v, page_table, pos, n_tok=n_tok)
-    active = None if n_tok is None else n_tok > 0
-    with jax.named_scope("attention"):
-        o = attention.paged_prefill_attention(cfg, q, ka, va, page_table,
-                                              pos, window=spec.window,
-                                              block_q=64, active=active,
-                                              impl=impl)
+        o = attention.paged_attention(cfg, q, ka, va, page_table, pos,
+                                      n_tok, window=spec.window, impl=impl)
     with jax.named_scope("out_proj"):
         h = attention.out_proj(cfg, p["mixer"], o)
         if cfg.double_norm:
@@ -634,29 +567,25 @@ class LM:
         return tuple({n: jnp.zeros(shape, cfg.compute_dtype)
                       for n in ("k", "v")} for _ in cfg.pattern)
 
-    def prefill_paged(self, params, arena, page_tables, tokens, pos0,
-                      active=None, impl="auto"):
-        """One teacher-forced chunk of prompt prefill over the paged pool.
+    def step_paged(self, params, arena, page_tables, tokens, pos, n_tok=None,
+                   write="tokens", impl="auto"):
+        """One window of every row through every layer over the paged pool.
 
-        tokens: (B, C) with C == BLOCK; pos0: (B,) block-aligned chunk
-        start.  Scatters the chunk's K/V into each row's write page and
-        returns logits for EVERY chunk position ((B, C, V) — the caller
-        picks the last real token's row; pad tail K/V is overwritten by
-        later writes before any mask exposes it), plus the updated arena.
+        tokens: (B, W), token m of row b at position ``pos[b] + m``;
+        page_tables: (B, n_pg) physical page per logical block; ``n_tok``,
+        ``write`` and ``impl`` as in ``layer_paged``.  Returns (B, W, V)
+        logits for every window position and the updated arena.
 
-        ``active`` is an optional (B,) bool mask for batched admission:
-        the admitted requests' uncached chunks are packed one per row of
-        ONE shared chunk grid (several rows may hold consecutive chunks
-        of one request, each row with the request's whole page table), and
-        rows the last dispatch of an admission leaves empty are masked
-        (scratch-page writes, zeroed output) — K co-routed siblings cost
-        ceil(sum(chunks) / B) dispatches instead of sum(chunks).
-
-        ``impl`` picks the paged attention of every layer (``attention.
-        paged_prefill_attention``): the kernel on a TPU by default."""
+        The engine's programs: the decode step (W = 1, "tokens", the last
+        logits), the admission prefill grid (W = BLOCK, "page": the
+        admitted requests' uncached chunks packed one per row, several
+        rows per request, each with the request's whole page table; pad
+        tail K/V is overwritten by later writes before any mask exposes
+        it), the speculative verify window (W = spec_k + 1, "tokens": its
+        logits equal W sequential decodes) and the query-only replay of a
+        fully cached prompt (W = 1, None)."""
         cfg = self.cfg
-        B, C = tokens.shape
-        positions = pos0[:, None] + jnp.arange(C)[None]
+        positions = pos[:, None] + jnp.arange(tokens.shape[1])[None]
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, positions)
 
@@ -664,72 +593,8 @@ class LM:
             bp, ar = xs
             new = []
             for i, spec in enumerate(cfg.pattern):
-                x, a = layer_prefill_paged(cfg, spec, bp[i], x, pos0,
-                                           ar[i], page_tables,
-                                           active=active, impl=impl)
-                new.append(a)
-            return constraints.constrain_batch(x), tuple(new)
-
-        x, arena = jax.lax.scan(body, x, (params["blocks"], arena))
-        with jax.named_scope("logits"):
-            x = common.apply_norm(cfg, params["final_norm"], x)
-            return self._logits(params, x), arena
-
-    def decode_paged(self, params, arena, page_tables, tokens, pos,
-                     active=None, write=True, impl="auto"):
-        """Paged analogue of ``decode``: tokens (B, 1), pos (B,),
-        page_tables (B, n_pg) physical page per logical block.  With
-        ``write=False`` the arena is returned untouched (query-only replay
-        for full prefix hits — aliased pages are never mutated).  ``impl``
-        picks the paged attention, as in ``prefill_paged``."""
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self._embed(params, tokens, pos[:, None])
-
-        def body(x, xs):
-            bp, ar = xs
-            new = []
-            for i, spec in enumerate(cfg.pattern):
-                x, a = layer_decode_paged(cfg, spec, bp[i], x, pos, ar[i],
-                                          page_tables, active=active,
-                                          write=write, impl=impl)
-                new.append(a)
-            return constraints.constrain_batch(x), tuple(new)
-
-        x, arena = jax.lax.scan(body, x, (params["blocks"], arena))
-        with jax.named_scope("logits"):
-            x = common.apply_norm(cfg, params["final_norm"], x)
-            logits = self._logits(params, x[:, -1])
-        return logits, arena
-
-    def verify_paged(self, params, arena, page_tables, tokens, pos,
-                     n_tok=None, impl="auto"):
-        """Speculative multi-token verify over the paged pool.
-
-        tokens: (B, W) — per row, the committed next token followed by up
-        to W-1 draft tokens; pos: (B,) absolute position of the window's
-        first token; n_tok: (B,) real tokens per row (ragged drafts, 0 =
-        masked slot).  Extends ``decode_paged`` to a W-token window in ONE
-        dispatch: the window's K/V is scattered by token position (pad and
-        dead rows go to the scratch page) and attention is causal inside
-        the window, so the returned (B, W, V) logits equal W sequential
-        single-token decodes — the scheduler accepts the longest draft
-        prefix matching greedy argmax and rolls back rejected tail KV by
-        simply not advancing the row position (append-only pages).
-        ``impl`` picks the paged attention, as in ``prefill_paged``."""
-        cfg = self.cfg
-        B, W = tokens.shape
-        positions = pos[:, None] + jnp.arange(W)[None]
-        with jax.named_scope("embed"):
-            x = self._embed(params, tokens, positions)
-
-        def body(x, xs):
-            bp, ar = xs
-            new = []
-            for i, spec in enumerate(cfg.pattern):
-                x, a = layer_verify_paged(cfg, spec, bp[i], x, pos, ar[i],
-                                          page_tables, n_tok=n_tok,
-                                          impl=impl)
+                x, a = layer_paged(cfg, spec, bp[i], x, pos, ar[i],
+                                   page_tables, n_tok, write, impl)
                 new.append(a)
             return constraints.constrain_batch(x), tuple(new)
 

@@ -141,7 +141,7 @@ class RealEngine:
                                  for s in cfg.pattern)
         self.batched_traces = 0   # compilations of the slot-pool decode
         self.batched_prefill_traces = 0   # compilations of batched admission
-        self.prefill_dispatches = 0       # jitted prefill_paged calls issued
+        self.prefill_dispatches = 0       # prefill grid dispatches issued
         self.prefill_tokens = 0           # real (non-pad) tokens prefilled
         self.prefill_slots = 0            # grid rows x BLOCK dispatched
         self.pages_evicted = 0            # pages freed by allocator pressure
@@ -153,7 +153,7 @@ class RealEngine:
         self.recorder = Recorder()
         # speculative decode counters (scheduler-driven verify rounds)
         self.spec_traces = 0      # compilations of the batched verify
-        self.spec_dispatches = 0  # verify_paged dispatches issued
+        self.spec_dispatches = 0  # verify window dispatches issued
         self.spec_tokens = 0      # tokens committed by verify rounds
         self.spec_drafted = 0     # draft tokens proposed
         self.spec_accepted = 0    # draft tokens accepted (== model argmax)
@@ -219,31 +219,36 @@ class RealEngine:
             donate = () if jax.default_backend() == "cpu" else (1,)
 
             def _prefill_paged(params, arena, pt, tok, pos0):
-                return model.prefill_paged(params, arena, pt, tok, pos0)
+                return model.step_paged(params, arena, pt, tok, pos0,
+                                        write="page")
 
             def _decode_paged(params, arena, pt, tok, pos):
-                return model.decode_paged(params, arena, pt, tok, pos)
+                logits, arena = model.step_paged(params, arena, pt, tok, pos)
+                return logits[:, -1], arena
 
             def _query_paged(params, arena, pt, tok, pos):
-                logits, _ = model.decode_paged(params, arena, pt, tok, pos,
-                                               write=False)
-                return logits
+                logits, _ = model.step_paged(params, arena, pt, tok, pos,
+                                             write=None)
+                return logits[:, -1]
 
             def _decode_paged_batched(params, arena, pt, tok, pos, active):
                 self.batched_traces += 1   # trace-time side effect only
-                return model.decode_paged(params, arena, pt, tok, pos,
-                                          active=active)
+                logits, arena = model.step_paged(
+                    params, arena, pt, tok, pos,
+                    n_tok=active.astype(jnp.int32))
+                return logits[:, -1], arena
 
             def _prefill_paged_batched(params, arena, pt, tok, pos0,
                                        active):
                 self.batched_prefill_traces += 1   # trace-time only
-                return model.prefill_paged(params, arena, pt, tok, pos0,
-                                           active=active)
+                return model.step_paged(
+                    params, arena, pt, tok, pos0,
+                    n_tok=jnp.where(active, tok.shape[1], 0), write="page")
 
             def _verify_paged_batched(params, arena, pt, tok, pos, n_tok):
                 self.spec_traces += 1   # trace-time side effect only
-                return model.verify_paged(params, arena, pt, tok, pos,
-                                          n_tok=n_tok)
+                return model.step_paged(params, arena, pt, tok, pos,
+                                        n_tok=n_tok)
 
             self._prefill_paged = jax.jit(_prefill_paged,
                                           donate_argnums=donate)
@@ -376,7 +381,7 @@ class RealEngine:
         assert pages, "empty page export"
         mode = mode or self.kv_wire_mode
         gathered = arena_gather_pages(self.arena, pages)
-        layers = [{n: compress_kv_blocks(layer[n], mode) for n in ("k", "v")}
+        layers = [{n: compress_kv_blocks(a, mode) for n, a in layer.items()}
                   for layer in gathered]
         self.kv_exported_pages += len(pages)
         return {"n_pages": len(pages), "mode": mode, "layers": layers}
@@ -403,9 +408,8 @@ class RealEngine:
         try:
             n = int(buf["n_pages"])
             blocks = tuple(
-                {name: decompress_kv_blocks(layer[name],
-                                            self.cfg.compute_dtype)
-                 for name in ("k", "v")}
+                {name: decompress_kv_blocks(a, self.cfg.compute_dtype)
+                 for name, a in layer.items()}
                 for layer in buf["layers"])
         except Exception as e:
             raise ValueError(f"malformed page buffer: {e!r}") from e
@@ -551,9 +555,9 @@ class RealEngine:
 
         Prefix hits alias cached pages first exactly like
         ``prefill_request``.  The uncached chunks then queue request
-        after request, and each ``prefill_paged`` dispatch takes the next
-        ``batch`` of them, one per row, so one request may fill several
-        rows.  Each of its rows carries the request's whole page table (a
+        after request, and each grid dispatch (``_prefill_paged_batched``)
+        takes the next ``batch`` of them, one per row, so one request may
+        fill several rows.  Each of its rows carries the request's whole page table (a
         fresh page per chunk, allocated before the dispatch) and starts
         at its own chunk: every layer scatters all rows' K/V before any
         row attends, so a later chunk reads its earlier siblings' K/V and

@@ -12,7 +12,7 @@ bytes copied), a fresh page is allocated only when a row's position
 crosses a block boundary, and completion registers the row's pages with
 ``PrefixCache`` by reference and drops the request's refcount.  Paged
 admission is itself **batched**: every round drains the queue into all
-free slots through one shared-grid ``prefill_paged`` dispatch stream
+free slots through one shared-grid prefill dispatch stream
 (engine.prefill_requests) that packs the admitted requests' uncached
 chunks one per grid row — K admitted requests cost
 ceil(sum(chunks) / max_active) dispatches, not K chunk loops.  Dense
@@ -151,7 +151,7 @@ class Scheduler:
 
     def _admit_batch(self):
         """Paged admission for a whole round: drain the queue into every
-        free slot through ONE batched ``prefill_paged`` dispatch stream
+        free slot through ONE batched prefill-grid dispatch stream
         (engine.prefill_requests) — the admitted requests' uncached chunks
         fill the ``max_active``-row grid one per row, so K admitted
         requests cost ceil(sum(chunks) / max_active) dispatches instead of

@@ -3,7 +3,7 @@
 Multi-node acceptance: with 2+ model nodes on SimNet, affinity routing is
 token-identical to load-only routing while doing strictly less duplicate
 prefill work, and a whole admission round of co-routed siblings costs ONE
-batched ``prefill_paged`` dispatch (shared chunk grid, masked tail rows).
+batched prefill grid dispatch (shared chunk grid, masked tail rows).
 
 Deliberately hypothesis-free so it runs even without dev extras installed.
 """
@@ -35,7 +35,7 @@ SHARED = [7] * 64                       # two full blocks
 # ---------------------------------------------------- batched admission
 def test_admission_round_is_single_prefill_dispatch(gt):
     """K co-routed siblings whose divergence suffixes fit one BLOCK cost
-    exactly ONE prefill_paged dispatch for the whole admission round."""
+    exactly ONE prefill grid dispatch for the whole admission round."""
     cfg, model, params = gt
     eng = RealEngine(cfg, model, params, max_len=128)
     s = Scheduler(eng, max_active=4)

@@ -28,9 +28,7 @@ def _model(name, impl):
             for s in cfg.pattern))
     model = build_model(cfg)
     if impl != "auto":
-        for fn in ("prefill_paged", "decode_paged", "verify_paged"):
-            setattr(model, fn,
-                    functools.partial(getattr(LM, fn), model, impl=impl))
+        model.step_paged = functools.partial(LM.step_paged, model, impl=impl)
     return cfg, model
 
 

@@ -8,6 +8,7 @@ tests/test_page_pool_props.py.
 import random
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -88,6 +89,47 @@ def test_allocator_randomized_invariants():
             assert a.refcount(p) == rc
         assert a.used_count == len(live)
         a.check()
+
+
+# ------------------------------------------------------- the paged step
+# (write, W, live row's position, live row's real tokens, live row's
+# pages the window covers): the grid's page-aligned chunk, the decode
+# step's one token, and a ragged verify window across a page boundary
+# whose pad token goes to the scratch page
+WRITES = {"page": ("page", BLOCK, BLOCK, BLOCK, {2}),
+          "tokens-1": ("tokens", 1, BLOCK + 5, 1, {2}),
+          "tokens-3": ("tokens", 3, 2 * BLOCK - 1, 2, {2, 3}),
+          "replay": (None, 1, BLOCK + 5, 1, set())}
+
+
+@pytest.mark.parametrize("case", list(WRITES))
+def test_step_writes_only_its_window_pages(gt, case):
+    """One ``step_paged`` over a live row and a dead one whose table
+    names live pages: the dead row changes only the scratch page 0, the
+    live row only the pages its window covers, and the replay no page."""
+    cfg, model, params = gt
+    write, W, pos, n, covered = WRITES[case]
+    arena = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(1), a.shape, a.dtype),
+        model.paged_arena_zeros(9, BLOCK))
+    pt = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+    tok = np.asarray([[(7 * m + 3) % cfg.vocab for m in range(W)]] * 2,
+                     np.int32)
+    pos0 = np.asarray([pos, BLOCK if write == "page" else BLOCK + 9],
+                      np.int32)
+    logits, after = model.step_paged(
+        params, arena, jnp.asarray(pt), jnp.asarray(tok), jnp.asarray(pos0),
+        n_tok=jnp.asarray([n, 0], jnp.int32), write=write)
+    assert logits.shape == (2, W, cfg.padded_vocab)
+    want = covered | ({NULL_PAGE} if write else set())
+    for b, a in zip(jax.tree.leaves(arena), jax.tree.leaves(after)):
+        diff = np.asarray(b != a).any(axis=(0, 3))          # (P, BLOCK)
+        assert set(np.flatnonzero(diff.any(axis=1))) == want
+        for page in covered:         # within a live page: its window only
+            first = (pt[0].tolist().index(page)) * BLOCK
+            offs = {p - first for p in range(pos, pos + n)
+                    if first <= p < first + BLOCK}
+            assert set(np.flatnonzero(diff[page])) == offs
 
 
 # ------------------------------------------------------- parity vs dense

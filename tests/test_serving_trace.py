@@ -221,23 +221,33 @@ def test_profiler_session_holds_the_programs_spans(gt, tmp_path):
     assert eng.recorder.gc_full >= 1
 
 
-@pytest.mark.parametrize("program", ["decode_paged", "prefill_paged",
-                                     "verify_paged"])
-def test_device_programs_carry_the_scopes(gt, program):
-    """Every scope names some op of the compiled program (the op names
-    the device trace's ops carry)."""
+@pytest.mark.parametrize("W, write", [(1, "tokens"), (BLOCK, "page"),
+                                      (3, "tokens"), (1, None)],
+                         ids=["decode_paged", "prefill_paged",
+                              "verify_paged", "query_paged"])
+def test_device_programs_carry_the_scopes(gt, W, write):
+    """Every scope names some op of the compiled ``step_paged`` program
+    (the op names the device trace's ops carry): the decode step, the
+    prefill grid, the verify window, and the query-only replay, which
+    writes nothing and so has no ``kv_write`` scope."""
     cfg, model, params = gt
     arena = model.paged_arena_zeros(5, BLOCK)
-    B, W = 2, {"decode_paged": 1, "prefill_paged": BLOCK,
-               "verify_paged": 3}[program]
+    B = 2
     pt = jnp.zeros((B, 4), jnp.int32)
     tok = jnp.zeros((B, W), jnp.int32)
     pos = jnp.zeros((B,), jnp.int32)
-    text = jax.jit(getattr(model, program)).lower(
-        params, arena, pt, tok, pos).compile().as_text()
+    n_tok = jnp.asarray([W, 0], jnp.int32)
+
+    def step(params, arena, pt, tok, pos, n_tok):
+        return model.step_paged(params, arena, pt, tok, pos, n_tok=n_tok,
+                                write=write)
+
+    text = jax.jit(step).lower(
+        params, arena, pt, tok, pos, n_tok).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     for scope in SCOPES:
-        assert any(f"/{scope}/" in n for n in names), scope
+        want = scope != "kv_write" or write is not None
+        assert any(f"/{scope}/" in n for n in names) == want, scope
 
 
 def test_result_times_a_request_from_its_submit(gt):

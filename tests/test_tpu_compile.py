@@ -122,8 +122,10 @@ def test_danube_decode_step_fits(one_chip, danube, impl):
         jax.ShapeDtypeStruct((BATCH,), jnp.bool_)))
 
     def step(params, arena, pt, tok, pos, active):
-        return model.decode_paged(params, arena, pt, tok, pos, active=active,
-                                  impl=impl)
+        logits, arena = model.step_paged(params, arena, pt, tok, pos,
+                                         n_tok=active.astype(jnp.int32),
+                                         impl=impl)
+        return logits[:, -1], arena
 
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, arena, *args).compile()
@@ -144,8 +146,9 @@ def test_danube_prefill_step_fits(one_chip, danube):
         jax.ShapeDtypeStruct((BATCH,), jnp.bool_)))
 
     def step(params, arena, pt, tok, pos0, active):
-        return model.prefill_paged(params, arena, pt, tok, pos0,
-                                   active=active, impl="pallas")
+        return model.step_paged(params, arena, pt, tok, pos0,
+                                n_tok=jnp.where(active, BLOCK, 0),
+                                write="page", impl="pallas")
 
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, arena, *args).compile()
